@@ -13,7 +13,7 @@ The **dequant section** extends the model to the packed serving hot path:
 for each decode matmul shape of the serve bench (the paper-100m full
 config's five projections, per batch size), it renders the dequant terms
 from the kernel's own tuning model (``kernels.dequant_matmul.tune``) —
-packed code bytes, dequant flops and time for the tile shape + strategy
+packed code bytes, dequant VPU ops and time for the tile shape + strategy
 ``choose_tiles`` actually picks — next to the dense-weight stream those
 bytes replace. Tile/strategy choices are thereby guided by the same
 analytic terms this table makes inspectable, not guessed: if a choice
@@ -149,7 +149,7 @@ def dequant_rows(batches=(1, 2, 4, 8), bits=4, n_codes=16, block=64):
             tiles=f"{c.tm}x{c.tk}x{c.tn}",
             strategy="decode" if c.decode else "lut",
             code_bytes=est["code_bytes"],
-            dequant_flops=est["dequant_flops"],
+            dequant_ops=est["dequant_ops"],
             dequant_time_s=est["dequant_time"],
             time_s=est["time"],
             dense_bytes=dense_bytes,

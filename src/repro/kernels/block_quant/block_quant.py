@@ -76,4 +76,5 @@ def block_quant(x: jnp.ndarray, codebook: jnp.ndarray, block: int = BLOCK,
             jax.ShapeDtypeStruct((rows, cols // block), jnp.float32),
         ],
         interpret=interpret,
+        name="block_quant",
     )(x, mids)

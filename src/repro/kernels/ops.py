@@ -1,7 +1,12 @@
-"""jit'd public wrappers over the Pallas kernels, with automatic fallback to
-the jnp oracle off-TPU (the container is CPU; interpret=True exercises the
-kernel bodies in tests)."""
+"""jit'd public wrappers over the Pallas kernels. On TPU they run the
+compiled kernels; off-TPU they take the jnp oracle (tests; interpret=True
+exercises the kernel bodies on the CPU). Nothing on the chip reaches the
+oracle branch — :func:`tpu_kernel_calls` counts the kernels in a compiled
+step to prove it."""
 from __future__ import annotations
+
+import collections
+import re
 
 import jax
 import jax.numpy as jnp
@@ -12,7 +17,6 @@ from .decode_attention.decode_attention import \
     decode_attention_quant as _daq_pallas
 from .decode_attention.ref import (decode_attention_quant_ref,
                                    dequant_kv_ref)
-from .dequant_matmul.dequant_matmul import TILE_M as MATMUL_TILE_M
 from .dequant_matmul.dequant_matmul import dequant_matmul as _dqm_pallas
 from .dequant_matmul.dequant_matmul import dequant_matmul_t as _dqmt_pallas
 from .dequant_matmul.ref import (dequant_matmul_decode_ref, dequant_matmul_ref,
@@ -28,6 +32,23 @@ from .dequant_matmul.ref import (dequant_matmul_decode_ref, dequant_matmul_ref,
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+# the ``name=`` of each pallas_call; a compiled TPU program names the
+# kernel's custom-call instruction after it
+KERNEL_NAMES = ("dequant_matmul", "dequant_matmul_t", "decode_attention",
+                "block_quant")
+_CUSTOM_CALL = re.compile(
+    r'^\s*(?:ROOT )?%([\w.-]+) = .*custom_call_target="tpu_custom_call"')
+
+
+def tpu_kernel_calls(hlo_text: str) -> collections.Counter:
+    """Count the ``tpu_custom_call`` instructions of a compiled TPU HLO
+    module (``compiled.as_text()``) by kernel name (instruction name less
+    its ``.N`` uniquifier)."""
+    names = (_CUSTOM_CALL.match(line) for line in hlo_text.splitlines())
+    return collections.Counter(re.sub(r"(\.\d+)+$", "", m.group(1))
+                               for m in names if m)
 
 
 def block_quant(x, codebook, block: int = 128, interpret: bool | None = None):

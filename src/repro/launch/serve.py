@@ -24,6 +24,7 @@ import numpy as np
 
 from repro import configs
 from repro.core import build_plan
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.api import get_family
 from repro.serve.engine import Request, ServeEngine
 
@@ -111,6 +112,7 @@ def main(argv=None):
                          "instead of re-prefilling; 0 disables prefix "
                          "reuse")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = configs.get_config(args.arch, args.variant)
     fam = get_family(cfg.family)
@@ -188,7 +190,9 @@ def main(argv=None):
                   f"{g['n_layers']} layer(s) x {g['length']} slots: "
                   f"{g['bytes']:,} bytes ({g['ratio_vs_dense']}x dense)")
     if args.traffic_replay is not None:
-        return _traffic_replay(eng, args)
+        report = _traffic_replay(eng, args)
+        _exit_if_degraded(eng)
+        return report
     rng = np.random.default_rng(0)
     for rid in range(args.requests):
         prompt = rng.integers(0, cfg.vocab, size=4).tolist()
@@ -203,12 +207,20 @@ def main(argv=None):
     print(f"[serve] {len(done)} requests, {n_tok} tokens in {dt:.2f}s "
           f"({n_tok / max(dt, 1e-9):.1f} tok/s)"
           + (f", {n_trunc} truncated at the KV budget" if n_trunc else "")
-          + (f", {n_failed} quarantined" if n_failed else "")
-          + (", degraded to dense" if eng.degraded else ""))
+          + (f", {n_failed} quarantined" if n_failed else ""))
     for g in done[:4]:
         print(f"  rid={g.rid} tokens={g.tokens}"
               + (f" FAILED: {g.fail_reason}" if g.failed else ""))
+    _exit_if_degraded(eng)
     return done
+
+
+def _exit_if_degraded(eng):
+    """A run that fell back to dense weights did not serve what was asked
+    for: exit non-zero, naming the error that caused the fallback."""
+    if eng.degraded:
+        raise SystemExit(f"[serve] engine degraded to dense weights: "
+                         f"{eng.degrade_reason}")
 
 
 def _auto_kv_format(cfg, fam, params, args) -> str:

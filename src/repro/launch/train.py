@@ -17,6 +17,7 @@ import jax
 
 from repro import configs
 from repro.data.pipeline import make_batch_fn
+from repro.launch.compile_cache import use_compile_cache
 from repro.train import AdamConfig, TrainConfig, train
 from repro.train.qat import qat_plan_for
 
@@ -41,6 +42,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--metrics-out", default="")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     try:
         cfg = configs.get_config(args.arch, args.variant)

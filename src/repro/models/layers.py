@@ -617,10 +617,6 @@ def moe_block_ep(x, p: MoeParams, cfg):
     by model shards; every shard routes its (replicated-over-model) local
     tokens to its own experts; outputs psum over the model axis."""
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
 
     mesh, batch_axes, model_ax = _EP_MESH
     M = mesh.shape[model_ax]
@@ -692,20 +688,11 @@ def moe_block_ep(x, p: MoeParams, cfg):
             aux = jax.lax.pmean(aux, ax)
         return out.reshape(Bl, Tl, Dl), aux
 
-    try:
-        smap = shard_map(
-            local, mesh=mesh,
-            in_specs=(x_spec, P(None, None), P(model_ax, None, None),
-                      P(model_ax, None, None), P(model_ax, None, None)),
-            out_specs=(x_spec, P()),
-            check_vma=False)
-    except TypeError:  # older kwarg name
-        smap = shard_map(
-            local, mesh=mesh,
-            in_specs=(x_spec, P(None, None), P(model_ax, None, None),
-                      P(model_ax, None, None), P(model_ax, None, None)),
-            out_specs=(x_spec, P()),
-            check_rep=False)
+    smap = jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(x_spec, P(None, None), P(model_ax, None, None),
+                  P(model_ax, None, None), P(model_ax, None, None)),
+        out_specs=(x_spec, P()), check_vma=False)
     out, aux = smap(x, w_router, w_gate, w_up, w_down)
     if p.shared is not None:
         out = out + swiglu(x, p.shared)

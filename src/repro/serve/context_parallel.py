@@ -21,21 +21,6 @@ import jax.numpy as jnp
 NEG = -1e30
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """shard_map across JAX versions: the replication-check kwarg was renamed
-    check_rep → check_vma; try the new name, fall back to the old."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-
-
 def partial_attention(q, k, v, kv_positions, q_position, window=0):
     """One shard's partial attention. q: (B, 1, H, hd); k/v: (B, S_loc, K, hd).
     Returns (m, l, acc): (B, K, G), (B, K, G), (B, K, G, hd)."""
@@ -87,10 +72,10 @@ def cp_decode_attention(q, k_cache, v_cache, q_position, mesh, seq_axis,
         return combine_partials(ms, ls, accs)
 
     B, _, H, hd = q.shape
-    out = shard_map_compat(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, None, None, None), P(None, seq_axis, None, None),
                   P(None, seq_axis, None, None)),
-        out_specs=P(None, None, None, None),
+        out_specs=P(None, None, None, None), check_vma=False,
     )(q, k_cache, v_cache)
     return out.reshape(B, 1, H, hd).astype(q.dtype)

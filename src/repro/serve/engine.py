@@ -204,6 +204,7 @@ class ServeEngine:
         self.step_retries = step_retries
         self.dense_fallback = dense_fallback
         self.degraded = False     # dense fallback engaged (degrade_to_dense)
+        self.degrade_reason = ""  # why it engaged (the failing step's error)
         # engine step clock: device steps executed over the engine lifetime
         # (prefill chunks + decode steps), plus the prefill-phase breakdown
         # the shared-prefix benchmarks compare (prefill_slot_steps counts
@@ -586,6 +587,7 @@ class ServeEngine:
         if self.degraded:
             return
         self.degraded = True
+        self.degrade_reason = reason
         n = sum(1 for l in jax.tree.leaves(
             self.params, is_leaf=lambda x: isinstance(x, PackedTensor))
             if isinstance(l, PackedTensor))

@@ -243,7 +243,7 @@ class TestTransposedDequantMatmul:
     def test_grid_accumulation_over_blocked_axis(self):
         """D spans multiple tiles: accumulation along the blocked
         contraction axis must be exact."""
-        M, D, V = 128, 1024, 256   # D/TILE_N = 4 accumulation steps
+        M, D, V = 128, 1024, 256   # D spans several tiles
         cb = jnp.asarray(CODEBOOKS["int4"], jnp.float32)
         x = rand((M, D), jnp.bfloat16, seed=36)
         codes, scales = block_quant_ref(rand((V, D), seed=37, scale=0.1), cb)

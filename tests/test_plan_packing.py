@@ -193,7 +193,7 @@ class TestPackability:
 class TestNibbleRoundTrip:
     """Property tests for the K-dim nibble interleave (core.nibble)."""
 
-    @settings(max_examples=30)
+    @settings(max_examples=30, deadline=None)
     @given(k_half=st.integers(1, 200), n_blocks=st.integers(1, 7),
            lead=st.booleans(), seed=st.integers(0, 2**31 - 1))
     def test_pack_unpack_round_trip(self, k_half, n_blocks, lead, seed):
@@ -209,7 +209,7 @@ class TestNibbleRoundTrip:
         np.testing.assert_array_equal(np.asarray(unpack_nibbles(packed, K)),
                                       np.asarray(codes))
 
-    @settings(max_examples=20)
+    @settings(max_examples=20, deadline=None)
     @given(k_half=st.integers(1, 300), seed=st.integers(0, 2**31 - 1))
     def test_row_coords_locate_every_row(self, k_half, seed):
         """nibble_row_coords finds each logical row's byte row + nibble

@@ -114,6 +114,13 @@ class TestKernelParity:
         # a kv-chunk smaller than S exercises the online-softmax carry
         self._check("q8", S=32, schunk=8)
 
+    @pytest.mark.parametrize("fmt", ["q8", "q4"])
+    @pytest.mark.parametrize("ring", [False, True])
+    def test_ragged_last_chunk(self, fmt, ring):
+        # S not a multiple of the kv-chunk (kv_len + prefill slack): the
+        # rows past S in the last chunk must be masked, never read
+        self._check(fmt, S=30, schunk=8, ring=ring, window=8 if ring else 0)
+
     def test_traced_window(self):
         # window arrives as a traced scalar inside jitted steps
         pos = jnp.asarray([[20], [19]], jnp.int32)
