@@ -1,0 +1,9 @@
+"""Engine on the device: the share of the traced window in which no
+operation ran on the device while the host was in admission:
+``serve.admit``, ``serve.refill`` and the ``serve.seat`` inside them.
+The five ``host_idle_pct`` groups sum to ``device_idle_pct``."""
+from chipbench import program_trace
+
+
+def read(ctx):
+    return program_trace.idle_pct(ctx, "admit")

@@ -308,26 +308,33 @@ def decode_step(params, state, batch, cfg: ModelConfig):
     pos, adv, valid, st = ragged_prologue(
         state, batch, {"tm_x": 1, "cm_x": 1, "wkv": 1})
     tm_x, cm_x, wkv_s = st["tm_x"], st["cm_x"], st["wkv"]
-    x = embed_lookup(params["embed"], tokens, dtype=dt)
+    # named scopes (embed, time_mix, channel_mix, unembed) put the layer kind
+    # into each operation's op_name in the compiled step's metadata
+    with jax.named_scope("embed"):
+        x = embed_lookup(params["embed"], tokens, dtype=dt)
 
     def body(x, inputs):
         lp, tm, cm, s = inputs
-        h, (tm_new, s_new) = time_mix(
-            rms_norm(x, lp["norm_tm"], cfg.norm_eps), lp, cfg,
-            last_x=tm.astype(dt), s0=s, valid=valid)
-        x = x + h
-        h, cm_new = channel_mix(
-            rms_norm(x, lp["norm_cm"], cfg.norm_eps), lp, cfg,
-            last_x=cm.astype(dt), valid=valid)
+        with jax.named_scope("time_mix"):
+            h, (tm_new, s_new) = time_mix(
+                rms_norm(x, lp["norm_tm"], cfg.norm_eps), lp, cfg,
+                last_x=tm.astype(dt), s0=s, valid=valid)
+            x = x + h
+        with jax.named_scope("channel_mix"):
+            h, cm_new = channel_mix(
+                rms_norm(x, lp["norm_cm"], cfg.norm_eps), lp, cfg,
+                last_x=cm.astype(dt), valid=valid)
         return x + h, (tm_new.astype(tm.dtype), cm_new.astype(cm.dtype),
                        s_new)
 
     x, (tm, cm, wkv) = jax.lax.scan(
         body, x, (params["layers"], tm_x, cm_x, wkv_s))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = linear(x, params["unembed"], "btd,dv->btv")
+    with jax.named_scope("unembed"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = linear(x, params["unembed"], "btd,dv->btv").astype(
+            jnp.float32)
     new_state = {"tm_x": tm, "cm_x": cm, "wkv": wkv, "pos": pos + adv}
-    return logits.astype(jnp.float32), new_state
+    return logits, new_state
 
 
 def init(rng, cfg: ModelConfig):

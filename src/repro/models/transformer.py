@@ -227,7 +227,10 @@ def decode_step(params, state, batch, cfg: ModelConfig):
     groups = layer_groups(cfg.window_pattern())
     fmts = parse_kv_formats(cfg.kv_format, len(groups), cfg.hd)
     pos, adv, _, st = ring_prologue(state, batch, len(groups), formats=fmts)
-    x = embed_lookup(params["embed"], tokens, dtype=dt)
+    # named scopes (embed, attention, mlp, unembed) put the layer kind into
+    # each operation's op_name in the compiled step's metadata
+    with jax.named_scope("embed"):
+        x = embed_lookup(params["embed"], tokens, dtype=dt)
     positions = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None]  # (B, T)
 
     # quantised cache groups carry (codes, scales) as a QuantisedKV pytree;
@@ -248,25 +251,30 @@ def decode_step(params, state, batch, cfg: ModelConfig):
                 f"v{g}": vc.codes, f"v{g}s": vc.scales}
 
     def layer_decode(x, lp, k_cache, v_cache, window, ring, codebook=None):
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q, k_new, v_new = qkv_project(h, _layer_attn_params(lp), positions, cfg)
-        k_cache = update_kv_cache(k_cache, k_new, pos, ring=ring,
-                                  codebook=codebook)
-        v_cache = update_kv_cache(v_cache, v_new, pos, ring=ring,
-                                  codebook=codebook)
-        o = chunked_decode_attention(q, k_cache, v_cache, positions,
-                                     window=window, ring=ring,
-                                     codebook=codebook)
-        x = x + linear(o, lp["wo"], "btnh,nhd->btd")
-        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        if cfg.n_experts:
-            moe = MoeParams(
-                lp["w_router"], lp["we_gate"], lp["we_up"], lp["we_down"],
-                shared=(MlpParams(lp["ws_gate"], lp["ws_up"], lp["ws_down"])
-                        if cfg.n_shared_experts else None))
-            y, _ = moe_block(h, moe, cfg)
-        else:
-            y = swiglu(h, MlpParams(lp["w_gate"], lp["w_up"], lp["w_down"]))
+        with jax.named_scope("attention"):
+            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+            q, k_new, v_new = qkv_project(h, _layer_attn_params(lp),
+                                          positions, cfg)
+            k_cache = update_kv_cache(k_cache, k_new, pos, ring=ring,
+                                      codebook=codebook)
+            v_cache = update_kv_cache(v_cache, v_new, pos, ring=ring,
+                                      codebook=codebook)
+            o = chunked_decode_attention(q, k_cache, v_cache, positions,
+                                         window=window, ring=ring,
+                                         codebook=codebook)
+            x = x + linear(o, lp["wo"], "btnh,nhd->btd")
+        with jax.named_scope("mlp"):
+            h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+            if cfg.n_experts:
+                moe = MoeParams(
+                    lp["w_router"], lp["we_gate"], lp["we_up"], lp["we_down"],
+                    shared=(MlpParams(lp["ws_gate"], lp["ws_up"],
+                                      lp["ws_down"])
+                            if cfg.n_shared_experts else None))
+                y, _ = moe_block(h, moe, cfg)
+            else:
+                y = swiglu(h, MlpParams(lp["w_gate"], lp["w_up"],
+                                        lp["w_down"]))
         return x + y, k_cache, v_cache
 
     codebooks = [None if f == "f32" else kv_codebook(f) for f in fmts]
@@ -332,9 +340,10 @@ def decode_step(params, state, batch, cfg: ModelConfig):
             new_caches.update(cache_entries(g, kg, vg))
 
     new_state = {**new_caches, "pos": pos + adv}
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = _unembed(x, params, cfg)
-    return logits.astype(jnp.float32), new_state
+    with jax.named_scope("unembed"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = _unembed(x, params, cfg).astype(jnp.float32)
+    return logits, new_state
 
 
 def prefill(params, batch, cfg: ModelConfig):

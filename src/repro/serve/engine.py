@@ -63,7 +63,11 @@ import numpy as np
 
 from repro.core.tensor_format import PackedTensor
 from repro.models.api import ModelConfig, ParamSpec, get_family
-from repro.train.fault_tolerance import StragglerMonitor, retry
+from repro.train.fault_tolerance import retry
+
+# a ``serve.*`` span in the profiler's trace, on the device trace's clock
+# (about a microsecond when no trace is active)
+_span = jax.profiler.TraceAnnotation
 
 
 def alloc_decode_state(fam, cfg: ModelConfig, batch_slots: int, kv_len: int,
@@ -169,8 +173,11 @@ class ServeEngine:
     default True): every PackedTensor leaf is dequantised, a single
     RuntimeWarning fires, and serving continues — disable it to let the
     failure propagate. Non-finite logits quarantine only the offending
-    slot (see :meth:`run`); ``straggler`` records per-step wall times
-    (:class:`~repro.train.fault_tolerance.StragglerMonitor`).
+    slot (see :meth:`run`).
+
+    Measurement: ``step_once`` writes ``serve.*`` spans into the profiler's
+    trace and keeps plain counters, always on; each ``serve.step`` span
+    ends with the :meth:`counters` snapshot, so a trace carries them.
     """
 
     def __init__(self, cfg: ModelConfig, params, batch_slots: int = 4,
@@ -212,6 +219,13 @@ class ServeEngine:
         self.steps_total = 0
         self.prefill_steps = 0
         self.prefill_slot_steps = 0
+        # work counted where it happens (see counters()): tokens the steps
+        # had to process against the token rows they computed (B x T), tokens
+        # sampled, and logits bytes copied to the host
+        self.tokens_valid = 0
+        self.tokens_computed = 0
+        self.tokens_emitted = 0
+        self.logits_host_bytes = 0
         # front-end hooks (see serve.scheduler). admission_hook(engine) runs
         # before every slot-fill pass — a scheduler releases arrivals into
         # the queue (priority/aging order) there; on_admit(engine, slot,
@@ -219,7 +233,6 @@ class ServeEngine:
         # forks pooled shared-prefix KV into the slot there.
         self.admission_hook = None
         self.on_admit = None
-        self.straggler = StragglerMonitor()
         self._state = self._zero_state()
         self._slots: List[Optional[Generation]] = [None] * batch_slots
         self._queue: List[Request] = []
@@ -230,8 +243,12 @@ class ServeEngine:
         # batch["reset"] so the jitted step wipes the predecessor's state
         # (quarantine raises the same bit to wipe a poisoned slot)
         self._needs_reset = np.zeros(batch_slots, bool)
-        self._step = jax.jit(
-            lambda p, s, b: self.fam.decode_step(p, s, b, self.cfg))
+
+        def serve_step(params, state, batch):
+            return self.fam.decode_step(params, state, batch, self.cfg)
+
+        # a named function: the trace and the HLO module are jit_serve_step
+        self._step = jax.jit(serve_step)
         self._cross_prefill = (jax.jit(
             lambda p, f: self.fam.cross_prefill(p, f, self.cfg))
             if self.fam.cross_prefill is not None else None)
@@ -289,6 +306,17 @@ class ServeEngine:
                                   windowed=self.windowed_cache)
 
     # ------------------------------------------------------------ accounting
+    def counters(self) -> dict:
+        """A flat snapshot of the engine's counters since it was built:
+        steps (``steps_total``, ``prefill_steps``, ``prefill_slot_steps``),
+        ``tokens_valid`` (tokens the steps had to process) against
+        ``tokens_computed`` (the B x T token rows they computed),
+        ``tokens_emitted`` and ``logits_host_bytes`` copied to the host."""
+        return {k: getattr(self, k) for k in (
+            "steps_total", "prefill_steps", "prefill_slot_steps",
+            "tokens_valid", "tokens_computed", "tokens_emitted",
+            "logits_host_bytes")}
+
     def weight_bytes(self) -> dict:
         """Resident parameter bytes, broken out so entries are comparable
         across architectures: ``codes`` (the quantised weight stream),
@@ -483,10 +511,54 @@ class ServeEngine:
         admission never waits for a wave to drain. Generations completing
         during the step are appended to ``finished``. Returns False (no
         step executed) when there is nothing to do — no live slot and the
-        admission pass produced none."""
-        self._admit()
-        if all(s is None for s in self._slots):
-            return False
+        admission pass produced none.
+
+        The iteration is a ``serve.step`` span (``T``, ``live``,
+        ``prefill_rows``; at its end the :meth:`counters` snapshot) around
+        one span per host phase, in order: ``serve.admit`` (hook and slot
+        fill, one ``serve.seat`` per request seated), ``serve.assemble``
+        (the batch and its copy to the device), ``serve.dispatch`` (the
+        step's enqueue, a compile or a retry), ``serve.device_wait``,
+        ``serve.logits_to_host`` (the copy alone), ``serve.sample`` and
+        ``serve.refill``."""
+        with _span("serve.step") as span:
+            with _span("serve.admit"):
+                self._admit()
+            if all(s is None for s in self._slots):
+                return False
+            with _span("serve.assemble"):
+                batch, t_valid, prefill_rows = self._assemble()
+            T = batch["tokens"].shape[1]
+            span.set_metadata(
+                T=T, live=sum(g is not None for g in self._slots),
+                prefill_rows=len(prefill_rows))
+            with _span("serve.dispatch"):
+                logits, self._state = self._execute_step(batch)
+            with _span("serve.device_wait"):
+                jax.block_until_ready(logits)
+            with _span("serve.logits_to_host"):
+                logits = np.asarray(logits)
+            self.steps_total += 1
+            if prefill_rows:
+                self.prefill_steps += 1
+                self.prefill_slot_steps += len(prefill_rows)
+            self.tokens_valid += int(t_valid.sum())
+            self.tokens_computed += self.B * T
+            self.logits_host_bytes += logits.nbytes
+            with _span("serve.sample"):
+                self._sample(logits, t_valid, finished)
+            # mid-wave refill: slots freed by _emit_token/_quarantine are
+            # reclaimed now, inside the wave, not at the next run() pass
+            with _span("serve.refill"):
+                self._admit()
+            span.set_metadata(**self.counters())
+        return True
+
+    def _assemble(self):
+        """The next step's batch over the live slots: each prefilling slot's
+        next prompt chunk (T = prefill_chunk while any slot prefills) or each
+        decoding slot's last token, staged on the device. Returns (batch,
+        t_valid, prefilling slots)."""
         prefill_rows = [
             i for i, g in enumerate(self._slots)
             if g is not None and self._slot_pos[i] < len(self._slot_prompt[i])]
@@ -519,14 +591,13 @@ class ServeEngine:
         if self._needs_reset.any():
             batch["reset"] = host_to_device(self._needs_reset)
             self._needs_reset[:] = False
-        ts = time.monotonic()
-        logits, self._state = self._execute_step(batch)
-        logits = np.asarray(logits)
-        self.straggler.record(time.monotonic() - ts)
-        self.steps_total += 1
-        if prefill_rows:
-            self.prefill_steps += 1
-            self.prefill_slot_steps += len(prefill_rows)
+        return batch, t_valid, prefill_rows
+
+    def _sample(self, logits: np.ndarray, t_valid: np.ndarray,
+                finished: List[Generation]):
+        """Advance every live slot by its valid tokens; each slot past its
+        prompt emits a token from its last valid logits row, or is
+        quarantined if that row is not finite; then the deadline check."""
         for i, g in enumerate(self._slots):
             if g is None:
                 continue
@@ -549,10 +620,6 @@ class ServeEngine:
                     self._quarantine(
                         i, g, f"deadline_steps={dl} exceeded with "
                         f"{len(g.tokens)} token(s) generated", finished)
-        # mid-wave refill: slots freed by _emit_token/_quarantine above are
-        # reclaimed now, inside the wave, not at the next run() pass
-        self._admit()
-        return True
 
     # --------------------------------------------------- fault tolerance
     def _execute_step(self, batch):
@@ -630,27 +697,33 @@ class ServeEngine:
         for i in range(self.B):
             if self._slots[i] is None and self._queue:
                 req = self._queue.pop(0)
-                g = Generation(rid=req.rid)
-                g.t_submit = getattr(req, "_t_submit", 0.0)
-                g.t_admit = time.monotonic()
-                g.queue_steps = self.steps_total - getattr(
-                    req, "_submit_step", self.steps_total)
-                self._slots[i] = g
-                g._req = req  # type: ignore
-                self._slot_prompt[i] = list(req.prompt)
-                self._slot_pos[i] = 0
-                self._slot_steps[i] = 0           # deadline clock restarts
-                # the first step after admission carries reset[i]=True: the
-                # jitted step zeroes the slot's KV rows and recurrent state
-                # (the predecessor's) before this prompt's first token
-                self._needs_reset[i] = True
-                if self._cross_prefill is not None:
-                    self._admit_cross(i, req)
-                # front-end hook: a scheduler forks pooled shared-prefix KV
-                # into the seated slot here (pure state surgery — may move
-                # _slot_pos past the pooled prefix and clear the reset bit)
-                if self.on_admit is not None:
-                    self.on_admit(self, i, req, g)
+                with _span("serve.seat", rid=req.rid, slot=i):
+                    self._seat(i, req)
+
+    def _seat(self, i: int, req: Request):
+        """Seat ``req`` in free slot ``i``: a new generation, the slot's
+        prompt and clocks, its reset bit, then the admission hooks."""
+        g = Generation(rid=req.rid)
+        g.t_submit = getattr(req, "_t_submit", 0.0)
+        g.t_admit = time.monotonic()
+        g.queue_steps = self.steps_total - getattr(
+            req, "_submit_step", self.steps_total)
+        self._slots[i] = g
+        g._req = req  # type: ignore
+        self._slot_prompt[i] = list(req.prompt)
+        self._slot_pos[i] = 0
+        self._slot_steps[i] = 0           # deadline clock restarts
+        # the first step after admission carries reset[i]=True: the
+        # jitted step zeroes the slot's KV rows and recurrent state
+        # (the predecessor's) before this prompt's first token
+        self._needs_reset[i] = True
+        if self._cross_prefill is not None:
+            self._admit_cross(i, req)
+        # front-end hook: a scheduler forks pooled shared-prefix KV
+        # into the seated slot here (pure state surgery — may move
+        # _slot_pos past the pooled prefix and clear the reset bit)
+        if self.on_admit is not None:
+            self.on_admit(self, i, req, g)
 
     def _admit_cross(self, i: int, req: Request):
         """Per-slot cross-attention prefill: encode this request's frames
@@ -689,6 +762,7 @@ class ServeEngine:
         if not g.tokens:
             g.t_first_token = time.monotonic()
         g.tokens.append(tok)
+        self.tokens_emitted += 1
         hit_budget = len(g.tokens) >= req.max_new_tokens
         hit_kv = self._slot_pos[i] >= self.kv_len - 1
         if hit_budget or hit_kv:

@@ -15,8 +15,7 @@ here, so the recovery paths are *drilled*, not assumed:
                           at chosen step indices — step retry and the
                           dense fallback must absorb it.
   stalls                  inject_slow_steps sleeps inside chosen steps —
-                          the run() watchdog and the straggler monitor
-                          must notice.
+                          the run() watchdog must notice.
   admission faults        drop_admissions / duplicate_admissions lose or
                           repeat queued requests — callers must see the
                           loss (fewer generations) or the duplicate-rid

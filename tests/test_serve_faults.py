@@ -242,13 +242,6 @@ class TestWatchdog:
         done = {g.rid: g.tokens for g in _quiet_run(eng_hit)}
         assert done == ref
 
-    def test_straggler_monitor_records_steps(self, ckpt):
-        plan, q, _ = ckpt
-        eng = _engine(plan, q)
-        eng.submit(Request(prompt=[1, 2, 3], max_new_tokens=4, rid=0))
-        eng.run()
-        assert len(eng.straggler._times) > 0
-
 
 class TestStepRetryAndFallback:
     def test_retry_absorbs_transient_failure(self, ckpt):
