@@ -55,7 +55,7 @@ from __future__ import annotations
 import time
 import warnings
 from dataclasses import dataclass, field
-from typing import List, Optional
+from typing import List, NamedTuple, Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -96,6 +96,32 @@ def host_to_device(buf: np.ndarray):
     ``jnp.asarray`` of an in-place-mutated buffer; routing through this
     helper is the sanctioned escape hatch."""
     return jnp.asarray(buf.copy())
+
+
+def _pick(logits, t_valid):
+    """Each slot's last valid row of a (B, T, V) step output, at
+    ``max(t_valid - 1, 0)``: its argmax (ties to the lowest index, as
+    ``np.argmax``), whether it is finite, and the (B, V) rows."""
+    at = jnp.maximum(t_valid - 1, 0)[:, None, None]
+    rows = jnp.take_along_axis(logits, at, axis=1)[:, 0]
+    return (jnp.argmax(rows, -1).astype(jnp.int32),
+            jnp.isfinite(rows).all(-1), rows)
+
+
+class Picked(NamedTuple):
+    """One step's output as the host holds it: each slot's greedy token
+    and whether its last valid logits row is finite, copied every step,
+    and those (B, V) rows themselves, copied to the host (numpy) only on a
+    step where a slot that emits samples at ``temperature > 0``, else left
+    on the device."""
+    token: np.ndarray
+    finite: np.ndarray
+    rows: Union[np.ndarray, jax.Array]
+
+    @property
+    def shape(self):
+        """(B, V): the rows the tokens were picked from."""
+        return self.rows.shape
 
 
 @dataclass
@@ -221,11 +247,13 @@ class ServeEngine:
         self.prefill_slot_steps = 0
         # work counted where it happens (see counters()): tokens the steps
         # had to process against the token rows they computed (B x T), tokens
-        # sampled, and logits bytes copied to the host
+        # emitted, bytes of the step's pick copied to the host, and the steps
+        # that copied the last logits rows for sampling
         self.tokens_valid = 0
         self.tokens_computed = 0
         self.tokens_emitted = 0
         self.logits_host_bytes = 0
+        self.logits_rows_to_host = 0
         # front-end hooks (see serve.scheduler). admission_hook(engine) runs
         # before every slot-fill pass — a scheduler releases arrivals into
         # the queue (priority/aging order) there; on_admit(engine, slot,
@@ -249,6 +277,8 @@ class ServeEngine:
 
         # a named function: the trace and the HLO module are jit_serve_step
         self._step = jax.jit(serve_step)
+        self._pick = jax.jit(_pick)
+        self._compile_pick()
         self._cross_prefill = (jax.jit(
             lambda p, f: self.fam.cross_prefill(p, f, self.cfg))
             if self.fam.cross_prefill is not None else None)
@@ -305,17 +335,33 @@ class ServeEngine:
                                   slack=self.prefill_chunk,
                                   windowed=self.windowed_cache)
 
+    def _compile_pick(self):
+        """Compile ``_pick`` for both step variants (T = 1 and T =
+        ``prefill_chunk``) now, so no serving step compiles it. Every
+        family's ``decode_step`` returns (B, T, vocab) float32 logits; the
+        step itself is not traced here, so a step that fails to trace still
+        fails inside ``_execute_step``, where the dense fallback catches
+        it."""
+        B = self.B
+        for T in sorted({1, self.prefill_chunk}):
+            self._pick.lower(
+                jax.ShapeDtypeStruct((B, T, self.cfg.vocab), jnp.float32),
+                jax.ShapeDtypeStruct((B,), jnp.int32)).compile()
+
     # ------------------------------------------------------------ accounting
     def counters(self) -> dict:
         """A flat snapshot of the engine's counters since it was built:
         steps (``steps_total``, ``prefill_steps``, ``prefill_slot_steps``),
         ``tokens_valid`` (tokens the steps had to process) against
         ``tokens_computed`` (the B x T token rows they computed),
-        ``tokens_emitted`` and ``logits_host_bytes`` copied to the host."""
+        ``tokens_emitted``, ``logits_host_bytes`` copied to the host (each
+        step's (B,) tokens and finiteness bits, and the (B, V) last rows on
+        a step that copied them) and ``logits_rows_to_host``, the steps that
+        copied those rows because an emitting slot samples."""
         return {k: getattr(self, k) for k in (
             "steps_total", "prefill_steps", "prefill_slot_steps",
             "tokens_valid", "tokens_computed", "tokens_emitted",
-            "logits_host_bytes")}
+            "logits_host_bytes", "logits_rows_to_host")}
 
     def weight_bytes(self) -> dict:
         """Resident parameter bytes, broken out so entries are comparable
@@ -447,8 +493,9 @@ class ServeEngine:
         receive fewer generations than they submitted. Live slots keep
         their state; calling ``run`` again continues them.
 
-        Fault isolation: after each step the emitted logits row of every
-        decode-phase slot is checked for finiteness. A non-finite row
+        Fault isolation: after each step the last valid logits row of
+        every slot that emits is checked for finiteness, on the device (the
+        host copies one bit a slot). A non-finite row
         quarantines **only that slot** — the generation is returned
         ``failed`` with its partial tokens, the slot is evicted and its
         (possibly poisoned) state wiped through the ``batch["reset"]``
@@ -518,9 +565,13 @@ class ServeEngine:
         one span per host phase, in order: ``serve.admit`` (hook and slot
         fill, one ``serve.seat`` per request seated), ``serve.assemble``
         (the batch and its copy to the device), ``serve.dispatch`` (the
-        step's enqueue, a compile or a retry), ``serve.device_wait``,
-        ``serve.logits_to_host`` (the copy alone), ``serve.sample`` and
-        ``serve.refill``."""
+        enqueue of the step, of ``_pick`` and of the copies, a compile or a
+        retry), ``serve.device_wait``, ``serve.logits_to_host`` (what of the
+        copy the wait did not cover), ``serve.sample`` and ``serve.refill``.
+        The step's logits stay on the device: ``_pick`` takes each slot's
+        last valid row there, and the host copies its (B,) argmax tokens
+        and finiteness bits, and the (B, V) rows only on a step where an
+        emitting slot samples at ``temperature > 0``."""
         with _span("serve.step") as span:
             with _span("serve.admit"):
                 self._admit()
@@ -534,19 +585,28 @@ class ServeEngine:
                 prefill_rows=len(prefill_rows))
             with _span("serve.dispatch"):
                 logits, self._state = self._execute_step(batch)
+                token, finite, rows = self._pick(logits, batch["t_valid"])
+                copy_rows = self._needs_rows(t_valid)
+                wanted = (token, finite) + ((rows,) if copy_rows else ())
+                # each copy starts when the device has computed it, not
+                # when the host next looks
+                for a in wanted:
+                    a.copy_to_host_async()
             with _span("serve.device_wait"):
-                jax.block_until_ready(logits)
+                jax.block_until_ready((token, finite))
             with _span("serve.logits_to_host"):
-                logits = np.asarray(logits)
+                copied = jax.device_get(wanted)
+            picked = Picked(*copied) if copy_rows else Picked(*copied, rows)
             self.steps_total += 1
             if prefill_rows:
                 self.prefill_steps += 1
                 self.prefill_slot_steps += len(prefill_rows)
             self.tokens_valid += int(t_valid.sum())
             self.tokens_computed += self.B * T
-            self.logits_host_bytes += logits.nbytes
+            self.logits_host_bytes += sum(a.nbytes for a in copied)
+            self.logits_rows_to_host += copy_rows
             with _span("serve.sample"):
-                self._sample(logits, t_valid, finished)
+                self._sample(picked, t_valid, finished)
             # mid-wave refill: slots freed by _emit_token/_quarantine are
             # reclaimed now, inside the wave, not at the next run() pass
             with _span("serve.refill"):
@@ -593,21 +653,27 @@ class ServeEngine:
             self._needs_reset[:] = False
         return batch, t_valid, prefill_rows
 
-    def _sample(self, logits: np.ndarray, t_valid: np.ndarray,
+    def _needs_rows(self, t_valid: np.ndarray) -> bool:
+        """Whether a slot that emits a token this step samples at
+        ``temperature > 0``, and so needs its logits row on the host."""
+        return any(
+            g is not None and g._req.temperature > 0  # type: ignore
+            and self._slot_pos[i] + t_valid[i] >= len(self._slot_prompt[i])
+            for i, g in enumerate(self._slots))
+
+    def _sample(self, picked: Picked, t_valid: np.ndarray,
                 finished: List[Generation]):
         """Advance every live slot by its valid tokens; each slot past its
-        prompt emits a token from its last valid logits row, or is
+        prompt emits a token picked from its last valid logits row, or is
         quarantined if that row is not finite; then the deadline check."""
         for i, g in enumerate(self._slots):
             if g is None:
                 continue
-            v = int(t_valid[i])
-            self._slot_pos[i] += v
+            self._slot_pos[i] += int(t_valid[i])
             self._slot_steps[i] += 1
             if self._slot_pos[i] >= len(self._slot_prompt[i]):
-                row = logits[i, v - 1]
-                if np.isfinite(row).all():
-                    self._emit_token(i, g, row, finished)
+                if picked.finite[i]:
+                    self._emit_token(i, g, picked, finished)
                 else:
                     self._quarantine(
                         i, g, "non-finite logits at token index "
@@ -742,11 +808,13 @@ class ServeEngine:
         for key, val in entries.items():
             self._state[key] = self._state[key].at[:, i].set(val[:, 0])
 
-    def _emit_token(self, i: int, g: Generation, logits_row: np.ndarray,
+    def _emit_token(self, i: int, g: Generation, picked: Picked,
                     finished: List[Generation]):
+        """Slot ``i`` emits its next token: the device's argmax, or at
+        ``temperature > 0`` one drawn from its copied logits row."""
         req = g._req  # type: ignore
         if req.temperature > 0:
-            z = logits_row / req.temperature
+            z = picked.rows[i] / req.temperature
             p = np.exp(z - z.max())
             p /= p.sum()
             # seed from (rid, index): decoupled across slots — one stream
@@ -758,7 +826,7 @@ class ServeEngine:
                                          len(g.tokens)))
             tok = int(rng.choice(len(p), p=p))
         else:
-            tok = int(np.argmax(logits_row))
+            tok = int(picked.token[i])
         if not g.tokens:
             g.t_first_token = time.monotonic()
         g.tokens.append(tok)

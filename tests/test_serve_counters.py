@@ -71,13 +71,15 @@ def served(ckpt):
 
 
 def test_computed_tokens_and_logits_bytes_match_the_steps(served):
+    """Greedy requests only: each step copies a (B,) int32 token and a (B,)
+    bool finiteness bit to the host, never a logits row."""
     eng, shapes, _, _ = served
     assert eng.tokens_computed == sum(B * T for (B, T), _, _ in shapes)
+    assert eng.logits_rows_to_host == 0
     assert eng.logits_host_bytes == sum(
-        int(np.prod(lshape)) * np.dtype(dt).itemsize
-        for _, lshape, dt in shapes)
-    assert eng.logits_host_bytes == sum(
-        B * T * CFG.vocab * 4 for (B, T), _, _ in shapes)
+        lshape[0] * (np.dtype(np.int32).itemsize + np.dtype(bool).itemsize)
+        for _, lshape, _ in shapes)
+    assert eng.logits_host_bytes == sum(B * 5 for (B, T), _, _ in shapes)
 
 
 def test_valid_and_emitted_tokens_match_the_requests(served):
@@ -101,14 +103,15 @@ def test_counters_snapshot_agrees_with_the_attributes(served):
     assert c["prefill_slot_steps"] == eng.prefill_slot_steps
     assert c["prefill_steps"] == sum(T > 1 for (_, T), _, _ in shapes)
     for k in ("tokens_valid", "tokens_computed", "tokens_emitted",
-              "logits_host_bytes"):
+              "logits_host_bytes", "logits_rows_to_host"):
         assert c[k] == getattr(eng, k)
     assert all(isinstance(v, int) for v in c.values())
 
 
 def test_a_quarantined_request_counts_what_it_was_served(ckpt):
-    """A slot poisoned mid-run: its step still counts the rows and logits
-    it computed, and only the tokens really emitted count as emitted."""
+    """A slot poisoned mid-run: its step still counts the rows it computed
+    and the tokens and bits it copied, only the tokens really emitted count
+    as emitted, and quarantine needs no logits row on the host."""
     eng = _engine(ckpt)
     shapes = _record_shapes(eng)
     ctr = faults.inject_nan_logits(eng, slot=2, at_step=3)
@@ -119,8 +122,8 @@ def test_a_quarantined_request_counts_what_it_was_served(ckpt):
     assert eng.tokens_emitted == sum(len(g.tokens) for g in out) \
         < sum(MAX_NEW)
     assert eng.tokens_computed == sum(B * T for (B, T), _, _ in shapes)
-    assert eng.logits_host_bytes == sum(
-        B * T * CFG.vocab * 4 for (B, T), _, _ in shapes)
+    assert eng.logits_host_bytes == sum(B * 5 for (B, T), _, _ in shapes)
+    assert eng.logits_rows_to_host == 0
     assert eng.steps_total == len(shapes)
 
 
@@ -184,6 +187,6 @@ def test_each_step_span_ends_with_the_counters(traced):
     first = got[0]
     assert first["steps_total"] == 1
     assert first["tokens_computed"] == ENG_KW["batch_slots"] * steps[0]["T"]
-    assert first["logits_host_bytes"] == first["tokens_computed"] \
-        * CFG.vocab * 4
+    assert first["logits_host_bytes"] == ENG_KW["batch_slots"] * 5
+    assert first["logits_rows_to_host"] == 0
     assert 0 < first["tokens_valid"] <= first["tokens_computed"]
