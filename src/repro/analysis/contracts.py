@@ -44,6 +44,14 @@ class ContractReport:
         return not self.findings
 
 
+def _leaf_shapes(tree) -> dict:
+    """{leaf path: (shape, dtype)} of a state tree (entries may be lists
+    of per-layer arrays)."""
+    import jax
+    return {jax.tree_util.keystr(path): (tuple(v.shape), str(v.dtype))
+            for path, v in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
 def default_matrix() -> List[Tuple[str, object]]:
     """(tag, smoke config) for every assigned architecture."""
     from repro import configs
@@ -152,10 +160,8 @@ def verify_family(tag: str, cfg, *, batch: int = 2, kv_len: int = 24,
         if tuple(logits.shape[:2]) != (batch, T):
             fail(f"decode_step {kind}: logits shaped {logits.shape}, "
                  f"expected leading ({batch}, {T})")
-        in_tree = {k: (tuple(v.shape), str(v.dtype))
-                   for k, v in state_sds.items()}
-        out_tree = {k: (tuple(v.shape), str(v.dtype))
-                    for k, v in new_state.items()} \
+        in_tree = _leaf_shapes(state_sds)
+        out_tree = _leaf_shapes(new_state) \
             if isinstance(new_state, dict) else None
         if out_tree != in_tree:
             only_in = sorted(set(in_tree) - set(out_tree or {}))
@@ -218,11 +224,8 @@ def verify_family(tag: str, cfg, *, batch: int = 2, kv_len: int = 24,
                  f"kv_format={qfmt!r}: {type(e).__name__}: {e}",
                  "the quantised cache must serve through the same step")
         else:
-            q_in = {k: (tuple(v.shape), str(v.dtype))
-                    for k, v in qstate_sds.items()}
-            q_out = {k: (tuple(v.shape), str(v.dtype))
-                     for k, v in qnew.items()} \
-                if isinstance(qnew, dict) else None
+            q_in = _leaf_shapes(qstate_sds)
+            q_out = _leaf_shapes(qnew) if isinstance(qnew, dict) else None
             if q_out != q_in:
                 fail(f"decode_step under kv_format={qfmt!r}: state is not "
                      "a fixed point of the quantised specs",

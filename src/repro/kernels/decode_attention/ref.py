@@ -52,7 +52,7 @@ def dequant_kv_ref(codes, scales, codebook, bits: int, dtype=jnp.float32):
 def decode_attention_quant_ref(q, k_codes, k_scales, v_codes, v_scales,
                                codebook, q_positions, *, window=0,
                                ring: bool = False, bits: int = 8,
-                               dequant_dtype=jnp.float32):
+                               dequant_dtype=jnp.float32, scale=None):
     """Oracle: dequantise the whole cache, then run the dense serving
     path's masked chunked decode attention verbatim. Returns
     (B, T, H, hd) in ``q.dtype``."""
@@ -60,4 +60,4 @@ def decode_attention_quant_ref(q, k_codes, k_scales, v_codes, v_scales,
     k = dequant_kv_ref(k_codes, k_scales, codebook, bits, dequant_dtype)
     v = dequant_kv_ref(v_codes, v_scales, codebook, bits, dequant_dtype)
     return chunked_decode_attention(q, k, v, q_positions, window=window,
-                                    ring=ring)
+                                    ring=ring, scale=scale)

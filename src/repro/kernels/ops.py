@@ -130,32 +130,32 @@ def dequant_matmul_t_interpret(x, codes, scales, codebook, block: int = 128,
 def decode_attention_quant(q, k_codes, k_scales, v_codes, v_scales,
                            codebook, q_positions, window=0, *,
                            ring: bool = False, bits: int = 8,
-                           interpret: bool | None = None):
+                           interpret: bool | None = None, scale=None):
     """Masked decode attention straight from block-scaled KV codes — the
     quantised twin of ``models.layers.chunked_decode_attention``. Fused
     flash-decode Pallas kernel on TPU (codes dequantise in VMEM after the
     HBM read); compositional oracle (dequantise + the dense masked path)
     off-TPU. ``bits=4``: codes nibble-packed pairwise along the head
-    dim."""
+    dim. ``scale``: the score scale, default ``hd ** -0.5``."""
     if interpret is None:
         interpret = not on_tpu()
     if interpret and not on_tpu():
         return decode_attention_quant_ref(
             q, k_codes, k_scales, v_codes, v_scales, codebook, q_positions,
-            window=window, ring=ring, bits=bits)
+            window=window, ring=ring, bits=bits, scale=scale)
     return _daq_pallas(q, k_codes, k_scales, v_codes, v_scales, codebook,
                        q_positions, window, ring=ring, bits=bits,
-                       interpret=interpret)
+                       interpret=interpret, scale=scale)
 
 
 def decode_attention_quant_interpret(q, k_codes, k_scales, v_codes, v_scales,
                                      codebook, q_positions, window=0, *,
                                      ring: bool = False, bits: int = 8,
-                                     schunk=None):
+                                     schunk=None, scale=None):
     """Force the Pallas kernel body in interpret mode (tests)."""
     return _daq_pallas(q, k_codes, k_scales, v_codes, v_scales, codebook,
                        q_positions, window, ring=ring, bits=bits,
-                       interpret=True, schunk=schunk)
+                       interpret=True, schunk=schunk, scale=scale)
 
 
 def dequant_kv(codes, scales, codebook, bits: int = 8, dtype=jnp.float32):

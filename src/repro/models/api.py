@@ -65,7 +65,13 @@ class ModelConfig:
     ssm_state: int = 0
     d_inner: int = 0              # 0 -> 2 * d_model
     conv_kernel: int = 4
-    attn_every: int = 0           # zamba2: shared attn period
+    ssm_groups: int = 1           # B/C groups shared by the SSM heads
+    # zamba2: the layers that run a shared transformer block before their
+    # Mamba layer (the published ``hybrid_layer_ids``), the shared blocks
+    # they cycle through, and the rank of each application's MLP adapter
+    hybrid_layers: Tuple[int, ...] = ()
+    n_shared_blocks: int = 1
+    adapter_rank: int = 0         # 0 -> no adapter
     # --- enc-dec (whisper) ---
     n_enc_layers: int = 0
     enc_seq: int = 1500
@@ -89,6 +95,10 @@ class ModelConfig:
     remat: str = "full"           # none | full | dots
     # moe dispatch implementation: "sort" (capacity, EP-friendly) | "dense"
     moe_impl: str = "sort"
+
+    def __post_init__(self):
+        # JSON configurations give lists; the config stays hashable
+        object.__setattr__(self, "hybrid_layers", tuple(self.hybrid_layers))
 
     @property
     def hd(self) -> int:
@@ -228,7 +238,8 @@ def ragged_prologue(state, batch, reset_axes):
     zeroing the named per-request state entries (and pos) inside the jitted
     step. ``reset_axes`` maps each resettable state key to the index of its
     batch dim (families stack state differently: transformer/whisper KV is
-    (L, B, S, ...), zamba2's conv/ssm are (G, P, B, ...)).
+    (L, B, S, ...), zamba2's per-layer conv/ssm lists (B, ...)); an entry
+    may be a list of arrays, each wiped at that axis.
 
     Returns ``(pos, adv, valid, entries)``: ``entries`` holds the
     possibly-wiped arrays for exactly the ``reset_axes`` keys; ``valid`` is
@@ -244,11 +255,13 @@ def ragged_prologue(state, batch, reset_axes):
     reset = batch.get("reset")
     if reset is not None:
         rm = reset.astype(bool)
-        for key, ax in reset_axes.items():
-            a = entries[key]
+        def wipe(a, ax):
             shape = [1] * a.ndim
             shape[ax] = a.shape[ax]
-            entries[key] = jnp.where(rm.reshape(shape), 0, a)
+            return jnp.where(rm.reshape(shape), 0, a)
+
+        for key, ax in reset_axes.items():
+            entries[key] = jax.tree.map(lambda a: wipe(a, ax), entries[key])
         pos = jnp.where(rm, 0, pos)
     valid = (jnp.arange(T, dtype=jnp.int32)[None, :] < adv[:, None]
              if (T > 1 or t_valid is not None) else None)
@@ -264,7 +277,7 @@ def ring_prologue(state, batch, n_groups: int, extra_reset=None,
     plus the per-row ``k{g}s``/``v{g}s`` scale stacks for quantised
     groups (``formats``: one KV format per group, default all dense — a
     zeroed scale dequantises every code in the row to exactly 0.0), plus
-    any family extras (``extra_reset``, e.g. zamba2's conv/ssm at axis 2
+    any family extras (``extra_reset``, e.g. zamba2's conv/ssm at axis 0
     or rwkv6-style recurrent entries).
 
     Wiping a ring group on reset is defence in depth rather than a

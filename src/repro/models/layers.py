@@ -296,7 +296,7 @@ def qkv_project(x, p: AttnParams, positions, cfg, rope_on: bool = True):
 
 def flash_attention(q, k, v, q_positions, k_positions, *, causal: bool = True,
                     window: jnp.ndarray | int = 0, chunk: int = 1024,
-                    k_valid_len=None):
+                    k_valid_len=None, scale=None):
     """Chunked online-softmax attention (memory O(Tq·chunk), never
     materialises the full score matrix — required for the 32k cells).
 
@@ -304,12 +304,13 @@ def flash_attention(q, k, v, q_positions, k_positions, *, causal: bool = True,
     window: 0 = global; >0 = sliding window (only keys within `window`).
             May be a traced scalar (per-layer pattern scanning).
     k_valid_len: optional (B,) or scalar count of valid keys (padding mask).
+    scale: the score scale, default hd ** -0.5.
     """
     B, Tq, H, hd = q.shape
     Tk, K = k.shape[1], k.shape[2]
     G = H // K
     qg = q.reshape(B, Tq, K, G, hd)
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
 
     chunk = min(chunk, Tk)
     pad = (-Tk) % chunk
@@ -397,7 +398,7 @@ def decode_attention(q, k_cache, v_cache, q_position, *, window=0,
 
 
 def chunked_decode_attention(q, k_cache, v_cache, q_positions, *, window=0,
-                             ring=False, codebook=None):
+                             ring=False, codebook=None, scale=None):
     """Multi-token decode attention with **per-slot** positions: a chunk of
     T query tokens per batch row against that row's KV cache. Used for both
     single-token decode (T=1) and batched chunked prefill — slots need not
@@ -419,18 +420,19 @@ def chunked_decode_attention(q, k_cache, v_cache, q_positions, *, window=0,
     is queried, by which point the content is real — write-before-read),
     and never-written slots reconstruct negative. Requires
     ``S ≥ window + T - 1`` so ragged-chunk padding writes only clobber
-    keys already outside every reachable window (see serve.cache)."""
+    keys already outside every reachable window (see serve.cache).
+    ``scale`` is the score scale, default ``hd ** -0.5``."""
     if isinstance(k_cache, QuantisedKV):
         return kops.decode_attention_quant(
             q, k_cache.codes, k_cache.scales, v_cache.codes, v_cache.scales,
             codebook, q_positions, window, ring=ring,
-            bits=codebook_bits(codebook))
+            bits=codebook_bits(codebook), scale=scale)
     B, T, H, hd = q.shape
     S, K = k_cache.shape[1], k_cache.shape[2]
     G = H // K
     qg = q.reshape(B, T, K, G, hd)
     s = jnp.einsum("btkgh,bskh->btkgs", qg, k_cache.astype(qg.dtype))
-    s = s.astype(jnp.float32) * hd ** -0.5
+    s = s.astype(jnp.float32) * (hd ** -0.5 if scale is None else scale)
     if ring:
         from repro.serve.cache import ring_positions
         kv = ring_positions(q_positions[:, -1], S)                # (B, S)
@@ -506,6 +508,20 @@ def swiglu(x, p: MlpParams):
     u = linear(x, p.w_up, "btd,df->btf")
     h = jax.nn.silu(g) * u
     return linear(h, p.w_down, "btf,fd->btd")
+
+
+def gelu_gated_mlp(x, w_gate_up, w_down, adapter=None):
+    """``down(gelu(g) * u)`` (exact, erf gelu) with ``[g, u] = x @
+    w_gate_up`` (one (D, 2F) weight, gate half first) plus, when ``adapter
+    = (A, B)`` is given, the low-rank ``(x @ A) @ B`` added to both halves
+    before the gate (the per-application LoRA of Zamba2's shared MLP)."""
+    gu = linear(x, w_gate_up, "btd,df->btf")
+    if adapter is not None:
+        a, b = adapter
+        gu = gu + linear(linear(x, a, "btd,dr->btr"), b, "btr,rf->btf")
+    g, u = jnp.split(gu, 2, axis=-1)
+    return linear(jax.nn.gelu(g, approximate=False) * u, w_down,
+                  "btf,fd->btd")
 
 
 def gelu_mlp(x, w_in, w_out):
@@ -707,8 +723,9 @@ def router_load_balancing_loss(probs, choice, E):
     return E * jnp.sum(f * pbar)
 
 
-def causal_conv1d(x, w, state=None, n_valid=None):
-    """Depthwise causal conv over time. x: (B, T, C); w: (Kw, C).
+def causal_conv1d(x, w, state=None, n_valid=None, bias=None):
+    """Depthwise causal conv over time. x: (B, T, C); w: (Kw, C); ``bias``
+    (C,) is added to the output.
     With ``state`` ((B, Kw-1, C)) performs streaming decode; returns
     (y, new_state). ``n_valid`` ((B,) int32) marks how many leading tokens
     of each row are real (ragged chunks): the new state is then the Kw-1
@@ -720,6 +737,8 @@ def causal_conv1d(x, w, state=None, n_valid=None):
     else:
         xp = jnp.concatenate([state.astype(x.dtype), x], axis=1)
     y = sum(xp[:, i : i + x.shape[1], :] * w[i] for i in range(Kw))
+    if bias is not None:
+        y = y + bias
     if Kw <= 1:
         new_state = None
     elif n_valid is None:

@@ -185,8 +185,9 @@ class CacheSpec:
     every layer (``kv_len + slack``) — the baseline of the byte
     accounting. ``layer_axis``/``head_axis`` name the logical mesh axes of
     the stacked lead dim and the head dim (families differ: transformer
-    stacks ``layers`` × ``kv_heads``, whisper ``layers`` × ``heads``,
-    zamba2 stacks its shared block's ``groups`` application points)."""
+    stacks ``layers`` × ``kv_heads``, whisper ``layers`` × ``heads``;
+    zamba2 has one single-layer cache group per hybrid application
+    point)."""
     groups: Tuple[CacheGroup, ...]
     batch: int
     kv_heads: int

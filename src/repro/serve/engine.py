@@ -247,13 +247,15 @@ class ServeEngine:
         self.prefill_slot_steps = 0
         # work counted where it happens (see counters()): tokens the steps
         # had to process against the token rows they computed (B x T), tokens
-        # emitted, bytes of the step's pick copied to the host, and the steps
-        # that copied the last logits rows for sampling
+        # emitted, bytes of the step's pick copied to the host, the steps
+        # that copied the last logits rows for sampling, and the bytes of
+        # recurrent state the steps read and wrote
         self.tokens_valid = 0
         self.tokens_computed = 0
         self.tokens_emitted = 0
         self.logits_host_bytes = 0
         self.logits_rows_to_host = 0
+        self.recurrent_state_bytes = 0
         # front-end hooks (see serve.scheduler). admission_hook(engine) runs
         # before every slot-fill pass — a scheduler releases arrivals into
         # the queue (priority/aging order) there; on_admit(engine, slot,
@@ -262,6 +264,7 @@ class ServeEngine:
         self.admission_hook = None
         self.on_admit = None
         self._state = self._zero_state()
+        self._recurrent_step_bytes = 2 * self.cache_bytes()["recurrent"]
         self._slots: List[Optional[Generation]] = [None] * batch_slots
         self._queue: List[Request] = []
         self._slot_pos = np.zeros(batch_slots, np.int32)
@@ -356,12 +359,16 @@ class ServeEngine:
         ``tokens_computed`` (the B x T token rows they computed),
         ``tokens_emitted``, ``logits_host_bytes`` copied to the host (each
         step's (B,) tokens and finiteness bits, and the (B, V) last rows on
-        a step that copied them) and ``logits_rows_to_host``, the steps that
-        copied those rows because an emitting slot samples."""
+        a step that copied them), ``logits_rows_to_host``, the steps that
+        copied those rows because an emitting slot samples, and
+        ``recurrent_state_bytes``: the decode state outside the KV cache
+        and the positions (``cache_bytes()["recurrent"]``), read and
+        written once by every step."""
         return {k: getattr(self, k) for k in (
             "steps_total", "prefill_steps", "prefill_slot_steps",
             "tokens_valid", "tokens_computed", "tokens_emitted",
-            "logits_host_bytes", "logits_rows_to_host")}
+            "logits_host_bytes", "logits_rows_to_host",
+            "recurrent_state_bytes")}
 
     def weight_bytes(self) -> dict:
         """Resident parameter bytes, broken out so entries are comparable
@@ -395,10 +402,12 @@ class ServeEngine:
         allocation vs the flat pre-ring full-length baseline, so
         ``cache_ratio_vs_uniform`` is the measured rolling-window saving.
         ``other`` is the non-KV decode state (recurrent/conv/ssm state,
-        whisper's cross-attention KV, positions); ``total`` sums the
-        actual allocated state tree."""
+        whisper's cross-attention KV, positions), ``recurrent`` the same
+        less the positions; ``total`` sums the actual allocated state
+        tree."""
         total = int(sum(int(l.size) * l.dtype.itemsize
                         for l in jax.tree.leaves(self._state)))
+        pos = self._state["pos"]
         out = {"total": total, "family": self.cfg.family}
         if self.fam.cache_spec is not None:
             spec = self.fam.cache_spec(
@@ -411,6 +420,7 @@ class ServeEngine:
             out.update({"kv": 0, "uniform_kv": 0,
                         "cache_ratio_vs_uniform": 1.0, "cache_groups": [],
                         "other": total})
+        out["recurrent"] = out["other"] - int(pos.size) * pos.dtype.itemsize
         return out
 
     # ------------------------------------------------------------------- api
@@ -605,6 +615,7 @@ class ServeEngine:
             self.tokens_computed += self.B * T
             self.logits_host_bytes += sum(a.nbytes for a in copied)
             self.logits_rows_to_host += copy_rows
+            self.recurrent_state_bytes += self._recurrent_step_bytes
             with _span("serve.sample"):
                 self._sample(picked, t_valid, finished)
             # mid-wave refill: slots freed by _emit_token/_quarantine are

@@ -275,7 +275,7 @@ class TestUnifiedPackedFamilies:
 
     FAMS = {
         "rwkv6-1.6b": ("['layers']['wr']", 10),
-        "zamba2-2.7b": ("['mamba']['out_proj']", 8),
+        "zamba2-2.7b": ("['mamba'][0]['out_proj']", 8),
         "whisper-large-v3": ("['dec']['self_wq']", 14),
     }
 

@@ -103,8 +103,10 @@ def test_counters_snapshot_agrees_with_the_attributes(served):
     assert c["prefill_slot_steps"] == eng.prefill_slot_steps
     assert c["prefill_steps"] == sum(T > 1 for (_, T), _, _ in shapes)
     for k in ("tokens_valid", "tokens_computed", "tokens_emitted",
-              "logits_host_bytes", "logits_rows_to_host"):
+              "logits_host_bytes", "logits_rows_to_host",
+              "recurrent_state_bytes"):
         assert c[k] == getattr(eng, k)
+    assert c["recurrent_state_bytes"] == 0      # a transformer has none
     assert all(isinstance(v, int) for v in c.values())
 
 

@@ -19,6 +19,7 @@ from repro.kernels import ops as kops
 from repro.kernels.decode_attention import (decode_attention_quant_ref,
                                             dequant_kv_ref,
                                             unpack_nibbles_hd)
+from repro.kernels.decode_attention import decode_attention as daq
 from repro.models import api as mapi
 from repro.models.layers import QuantisedKV, codebook_bits, quantise_kv
 from repro.serve.cache import (build_cache_spec, kv_bits, kv_codebook,
@@ -70,7 +71,8 @@ class TestKernelParity:
     semantics, per format × cache geometry."""
 
     def _check(self, fmt, *, B=2, S=24, K=2, H=4, hd=16, T=1,
-               window=0, ring=False, positions=None, schunk=None):
+               window=0, ring=False, positions=None, schunk=None,
+               scale=None):
         rng = jax.random.PRNGKey(hash((fmt, S, T, ring)) % 2**31)
         r1, r2, r3 = jax.random.split(rng, 3)
         kc, ks, cb = _quant_cache(r1, B, S, K, hd, fmt)
@@ -83,10 +85,10 @@ class TestKernelParity:
         bits = kv_bits(fmt)
         got = kops.decode_attention_quant_interpret(
             q, kc, ks, vc, vs, cb, positions, window, ring=ring, bits=bits,
-            schunk=schunk)
+            schunk=schunk, scale=scale)
         want = decode_attention_quant_ref(
             q, kc, ks, vc, vs, cb, positions, window=window, ring=ring,
-            bits=bits)
+            bits=bits, scale=scale)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
 
@@ -120,6 +122,28 @@ class TestKernelParity:
         # S not a multiple of the kv-chunk (kv_len + prefill slack): the
         # rows past S in the last chunk must be masked, never read
         self._check(fmt, S=30, schunk=8, ring=ring, window=8 if ring else 0)
+
+    @pytest.mark.parametrize("fmt,H,hd", [("q8", 8, 32), ("q8", 16, 32),
+                                          ("q4", 8, 64)])
+    def test_kv_head_blocks(self, fmt, H, hd, monkeypatch):
+        # no block of all 8 KV heads fits a zero budget, so the kernel
+        # takes two blocks of 4 (4 x hdc a whole 128-lane row): the
+        # head-block grid axis, multi-head (H = K) and grouped (H = 2K)
+        monkeypatch.setattr(daq, "VMEM_BUDGET", 0)
+        bits = kv_bits(fmt)
+        assert daq.choose_kv_block(8, H // 8, 4, hd, bits, 24) == 4
+        # no trace made under the default budget may be reused
+        daq.decode_attention_quant.clear_cache()
+        pos = jnp.asarray([[4, 5, 6, 7], [0, 1, 2, 3]], jnp.int32)
+        try:
+            self._check(fmt, K=8, H=H, hd=hd, T=4, positions=pos,
+                        scale=(hd / 2) ** -0.5)
+        finally:
+            daq.decode_attention_quant.clear_cache()
+
+    def test_scale(self):
+        # a score scale other than hd ** -0.5 (Zamba2's (hd / 2) ** -0.5)
+        self._check("q8", scale=(16 / 2) ** -0.5)
 
     def test_traced_window(self):
         # window arrives as a traced scalar inside jitted steps
