@@ -18,10 +18,13 @@ Mosaic lowers:
 * the cache is viewed as ``(B, S, K·hdc)`` codes and ``(B, S, K)`` scales
   (free reshapes), so every tile is a lane-dense 2-D block: a
   ``(sc, kb·hdc)`` code tile (``kb·hdc`` a multiple of 128, or ``kb = K``)
-  and the ``(sc, K)`` scales of every head. Each step expands the code
-  tile through the codebook select tree (``dequant_matmul.codepoints``,
-  codepoints in SMEM) and scales it per (token, head) with a 0/1 expansion
-  matmul that also picks the block's heads out of the scales.
+  and the ``(sc, K)`` scales of every head. Every KV codebook is a
+  uniform grid (``serve.cache.kv_codebook``), so each step decodes the
+  code tile affinely, ``lo + code · step`` (the grid's two scalars in
+  SMEM: one convert and a multiply-add per element, where a lookup would
+  take ``n_codes - 1`` selects), and scales it per (token, head) with a
+  0/1 expansion matmul that also picks the block's heads out of the
+  scales.
 * heads stay 2-D too: the wrapper lays each head block's queries out
   **head-block diagonal**, ``(T·kb·G, kb·hd)`` with query head ``h`` in the
   lanes of its KV head ``h // G``, so one matmul against the dequantised
@@ -48,7 +51,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.dequant_matmul.dequant_matmul import codepoints, row_chunk
+from repro.kernels.dequant_matmul.dequant_matmul import row_chunk
 
 NEG_INF = -1e30
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -59,13 +62,21 @@ def _dot(a, b, dims):
                                preferred_element_type=jnp.float32)
 
 
-def _dequant_parts(codes_ref, scales_ref, cb_ref, out_ref, valid_rows,
-                   head0, *, n_codes: int, bits: int, K: int, kb: int):
+def uniform_grid(codebook):
+    """``(lo, step)`` of a uniform codebook, ``codebook[c] = lo + c · step``
+    (f32 scalars), read from its first and last entries."""
+    cb = codebook.astype(jnp.float32)
+    return cb[0], (cb[-1] - cb[0]) / (cb.shape[0] - 1)
+
+
+def _dequant_parts(codes_ref, scales_ref, grid_ref, out_ref, valid_rows,
+                   head0, *, bits: int, K: int, kb: int):
     """Expand a (sc, kb·hdc) code tile of KV heads ``head0 .. head0 + kb``
-    into ``out_ref`` (P, sc, kb·w) f32 = codepoint × (token, head) scale,
-    the scales read from the (sc, K) tile of every head; 4-bit rows split
-    into low/high nibble parts. Rows at or past ``valid_rows`` (a ragged
-    last chunk) are zero."""
+    into ``out_ref`` (P, sc, kb·w) f32 = (lo + code · step) × (token, head)
+    scale, ``(lo, step)`` read from the SMEM ``grid_ref`` and the scales
+    from the (sc, K) tile of every head; 4-bit rows split into low/high
+    nibble parts. Rows at or past ``valid_rows`` (a ragged last chunk) are
+    zero."""
     sc, kw = out_ref.shape[1], out_ref.shape[2]
     w = kw // kb
     i = (jax.lax.broadcasted_iota(jnp.int32, (K, kw), 0) - head0) * w
@@ -80,7 +91,7 @@ def _dequant_parts(codes_ref, scales_ref, cb_ref, out_ref, valid_rows,
         rows = r0 + jax.lax.broadcasted_iota(jnp.int32, (ch, kw), 0)
         parts = [codes & 0xF, codes >> 4] if bits == 4 else [codes]
         for p, cp in enumerate(parts):
-            vals = codepoints(cp, cb_ref, n_codes) * s
+            vals = (cp.astype(jnp.float32) * grid_ref[1] + grid_ref[0]) * s
             out_ref[p, pl.ds(r0, ch), :] = jnp.where(rows < valid_rows,
                                                      vals, 0.0)
         return carry
@@ -88,10 +99,10 @@ def _dequant_parts(codes_ref, scales_ref, cb_ref, out_ref, valid_rows,
     jax.lax.fori_loop(0, sc // ch, body, 0)
 
 
-def _kernel(qp_ref, win_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref, cb_ref,
-            o_ref, m_ref, l_ref, acc_ref, kd_ref, vd_ref, *, bits: int,
-            n_codes: int, sc: int, S: int, ring: bool, T: int, HB: int,
-            K: int, kb: int):
+def _kernel(qp_ref, win_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref,
+            grid_ref, o_ref, m_ref, l_ref, acc_ref, kd_ref, vd_ref, *,
+            bits: int, sc: int, S: int, ring: bool, T: int, HB: int, K: int,
+            kb: int):
     b = pl.program_id(0)
     j = pl.program_id(2)
     nj = pl.num_programs(2)
@@ -104,10 +115,10 @@ def _kernel(qp_ref, win_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref, cb_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     valid = S - j * sc
-    kw = dict(n_codes=n_codes, bits=bits, K=K, kb=kb)
+    kw = dict(bits=bits, K=K, kb=kb)
     head0 = pl.program_id(1) * kb
-    _dequant_parts(kc_ref, ks_ref, cb_ref, kd_ref, valid, head0, **kw)
-    _dequant_parts(vc_ref, vs_ref, cb_ref, vd_ref, valid, head0, **kw)
+    _dequant_parts(kc_ref, ks_ref, grid_ref, kd_ref, valid, head0, **kw)
+    _dequant_parts(vc_ref, vs_ref, grid_ref, vd_ref, valid, head0, **kw)
     s = _dot(q_ref[0], kd_ref[0], ((1,), (1,)))            # (T·HB, sc)
     for p in range(1, n_parts):
         s += _dot(q_ref[p], kd_ref[p], ((1,), (1,)))
@@ -207,7 +218,8 @@ def decode_attention_quant(q, k_codes, k_scales, v_codes, v_scales,
     """Masked decode attention straight from quantised cache rows.
 
     q (B, T, H, hd); codes (B, S, K, hdc) uint8 (hdc = hd, or hd//2 nibble-
-    packed for bits=4); scales (B, S, K, 1) f32; q_positions (B, T) int32;
+    packed for bits=4); scales (B, S, K, 1) f32; ``codebook`` a uniform
+    grid (:func:`uniform_grid` reads its ends); q_positions (B, T) int32;
     ``window`` may be a traced scalar (0 = global); ``scale`` is the score
     scale (default ``hd ** -0.5``); :func:`choose_kv_block` sets the KV
     heads of a grid step. Returns (B, T, H, hd) in q.dtype — the
@@ -235,8 +247,8 @@ def decode_attention_quant(q, k_codes, k_scales, v_codes, v_scales,
         for p in parts], axis=1)
     win = jnp.reshape(jnp.asarray(window, jnp.int32), (1,))
     qp = q_positions.astype(jnp.int32)
-    kernel = functools.partial(_kernel, bits=bits, n_codes=codebook.shape[0],
-                               sc=sc, S=S, ring=ring, T=T, HB=HB, K=K, kb=kb)
+    kernel = functools.partial(_kernel, bits=bits, sc=sc, S=S, ring=ring,
+                               T=T, HB=HB, K=K, kb=kb)
     block = pl.BlockSpec((None, P, None, T * HB, kb * w),
                          lambda b, h, j, *_: (b, 0, h, 0, 0))
     codes = pl.BlockSpec((None, sc, kb * hdc), lambda b, h, j, *_: (b, j, h))
@@ -262,7 +274,7 @@ def decode_attention_quant(q, k_codes, k_scales, v_codes, v_scales,
         name="decode_attention",
     )(qp, win, qbd, k_codes.reshape(B, S, K * hdc),
       k_scales.reshape(B, S, K), v_codes.reshape(B, S, K * hdc),
-      v_scales.reshape(B, S, K), codebook.astype(jnp.float32))
+      v_scales.reshape(B, S, K), jnp.stack(uniform_grid(codebook)))
     # keep each head's own KV-head lane block, re-interleave nibble parts
     heads = jnp.arange(HB)
     out = out.reshape(B, P, nb, T, HB, kb, w)[:, :, :, :, heads, heads // G]

@@ -10,11 +10,13 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .block_quant.block_quant import block_quant as _bq_pallas
 from .block_quant.ref import block_quant_ref, block_dequant_ref
 from .decode_attention.decode_attention import \
     decode_attention_quant as _daq_pallas
+from .decode_attention.decode_attention import uniform_grid
 from .decode_attention.ref import (decode_attention_quant_ref,
                                    dequant_kv_ref)
 from .dequant_matmul.dequant_matmul import dequant_matmul as _dqm_pallas
@@ -127,6 +129,23 @@ def dequant_matmul_t_interpret(x, codes, scales, codebook, block: int = 128,
                         interpret=True, variant=variant)
 
 
+def check_uniform_grid(codebook):
+    """Raise ``ValueError`` unless a concrete codebook is the uniform grid
+    ``lo + c · step`` the decode-attention kernel decodes, to within one
+    ulp of its largest magnitude. A traced codebook (inside a jitted step)
+    cannot be seen and passes."""
+    if isinstance(codebook, jax.core.Tracer):
+        return
+    lo, step = uniform_grid(codebook)
+    cb = np.asarray(codebook, np.float32)
+    grid = np.asarray(lo + jnp.arange(cb.shape[0], dtype=jnp.float32) * step)
+    gap = float(np.max(np.abs(grid - cb)))
+    if not gap <= np.spacing(np.max(np.abs(cb))):
+        raise ValueError(
+            f"decode attention needs a uniform KV codebook: the "
+            f"{cb.shape[0]}-point codebook lies {gap:.3g} off lo + c * step")
+
+
 def decode_attention_quant(q, k_codes, k_scales, v_codes, v_scales,
                            codebook, q_positions, window=0, *,
                            ring: bool = False, bits: int = 8,
@@ -136,7 +155,10 @@ def decode_attention_quant(q, k_codes, k_scales, v_codes, v_scales,
     flash-decode Pallas kernel on TPU (codes dequantise in VMEM after the
     HBM read); compositional oracle (dequantise + the dense masked path)
     off-TPU. ``bits=4``: codes nibble-packed pairwise along the head
-    dim. ``scale``: the score scale, default ``hd ** -0.5``."""
+    dim. ``scale``: the score scale, default ``hd ** -0.5``. ``codebook``
+    is a uniform grid (every ``serve.cache.kv_codebook``); a concrete one
+    that is not raises ``ValueError``."""
+    check_uniform_grid(codebook)
     if interpret is None:
         interpret = not on_tpu()
     if interpret and not on_tpu():
@@ -153,6 +175,7 @@ def decode_attention_quant_interpret(q, k_codes, k_scales, v_codes, v_scales,
                                      ring: bool = False, bits: int = 8,
                                      schunk=None, scale=None):
     """Force the Pallas kernel body in interpret mode (tests)."""
+    check_uniform_grid(codebook)
     return _daq_pallas(q, k_codes, k_scales, v_codes, v_scales, codebook,
                        q_positions, window, ring=ring, bits=bits,
                        interpret=True, schunk=schunk, scale=scale)

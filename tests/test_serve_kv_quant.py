@@ -6,9 +6,11 @@ format parsing + cache-byte accounting; Fisher format allocation; and the
 serving stack end to end — per-family greedy drift under q8, prefix forks
 copying quantised rows, slot-reset isolation, and the ``quantised_cache``
 kill-switch reproducing the dense engine bit-exactly."""
+import functools
 import warnings
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -22,8 +24,8 @@ from repro.kernels.decode_attention import (decode_attention_quant_ref,
 from repro.kernels.decode_attention import decode_attention as daq
 from repro.models import api as mapi
 from repro.models.layers import QuantisedKV, codebook_bits, quantise_kv
-from repro.serve.cache import (build_cache_spec, kv_bits, kv_codebook,
-                               parse_kv_formats)
+from repro.serve.cache import (KV_FORMATS, build_cache_spec, kv_bits,
+                               kv_codebook, parse_kv_formats)
 from repro.serve.engine import Request, ServeEngine, greedy_generate
 from repro.serve.scheduler import Scheduler
 
@@ -141,6 +143,26 @@ class TestKernelParity:
         finally:
             daq.decode_attention_quant.clear_cache()
 
+    @pytest.mark.parametrize("fmt", ["q8", "q4"])
+    @pytest.mark.parametrize("layout", ["internlm2_gqa", "zamba2_mha"])
+    def test_served_head_layouts(self, fmt, layout, monkeypatch):
+        # the head layouts the chip cells serve, at small S: internlm2's
+        # grouped 8 KV heads x 2 of 128 in one block, and zamba2's MHA 32
+        # heads of 224 with its score scale over several head blocks
+        pos = jnp.asarray([[4, 5, 6, 7], [0, 1, 2, 3]], jnp.int32)
+        if layout == "internlm2_gqa":
+            assert daq.choose_kv_block(8, 2, 4, 128, kv_bits(fmt), 24) == 8
+            self._check(fmt, K=8, H=16, hd=128, T=4, positions=pos)
+            return
+        monkeypatch.setattr(daq, "VMEM_BUDGET", 0)
+        assert daq.choose_kv_block(32, 1, 4, 224, kv_bits(fmt), 24) < 32
+        daq.decode_attention_quant.clear_cache()
+        try:
+            self._check(fmt, K=32, H=32, hd=224, T=4, positions=pos,
+                        scale=(224 / 2) ** -0.5)
+        finally:
+            daq.decode_attention_quant.clear_cache()
+
     def test_scale(self):
         # a score scale other than hd ** -0.5 (Zamba2's (hd / 2) ** -0.5)
         self._check("q8", scale=(16 / 2) ** -0.5)
@@ -149,6 +171,68 @@ class TestKernelParity:
         # window arrives as a traced scalar inside jitted steps
         pos = jnp.asarray([[20], [19]], jnp.int32)
         self._check("q8", window=jnp.int32(6), positions=pos)
+
+
+def _subjaxprs(value):
+    if isinstance(value, jax.extend.core.ClosedJaxpr):
+        yield value.jaxpr
+    elif isinstance(value, jax.extend.core.Jaxpr):
+        yield value
+    elif isinstance(value, (tuple, list)):
+        for v in value:
+            yield from _subjaxprs(v)
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in _subjaxprs(v):
+                yield from _eqns(sub)
+
+
+class TestUniformGrid:
+    """The kernel decodes a code affinely, ``lo + code · step``: every KV
+    codebook must be that grid, a concrete codebook that is not is
+    refused, and no codebook select tree is left in the kernel body."""
+
+    @pytest.mark.parametrize("fmt", [f for f in KV_FORMATS if f != "f32"])
+    def test_kv_codebook_is_the_affine_grid(self, fmt):
+        cb = kv_codebook(fmt)
+        lo, step = daq.uniform_grid(cb)
+        grid = lo + jnp.arange(cb.shape[0], dtype=jnp.float32) * step
+        gap = np.max(np.abs(np.asarray(grid) - np.asarray(cb)))
+        assert gap <= np.spacing(np.float32(1.0))
+
+    @pytest.mark.parametrize("interpret", [False, True])
+    @pytest.mark.parametrize("n", [16, 256])
+    def test_non_uniform_codebook_is_refused(self, n, interpret):
+        fmt = "q4" if n == 16 else "q8"
+        kc, ks, _ = _quant_cache(jax.random.PRNGKey(0), 1, 8, 1, 16, fmt)
+        cb = jnp.linspace(-1.0, 1.0, n, dtype=jnp.float32) ** 3
+        q = jnp.ones((1, 1, 2, 16), jnp.float32)
+        call = (kops.decode_attention_quant_interpret if interpret
+                else kops.decode_attention_quant)
+        with pytest.raises(ValueError, match="uniform"):
+            call(q, kc, ks, kc, ks, cb, jnp.zeros((1, 1), jnp.int32),
+                 bits=kv_bits(fmt))
+
+    @pytest.mark.parametrize("fmt", ["q8", "q4"])
+    def test_kernel_body_has_no_select_tree(self, fmt):
+        # a codebook lookup takes n_codes - 1 selects a part (255 for q8);
+        # the affine decode leaves only the masks' few
+        kc, ks, cb = _quant_cache(jax.random.PRNGKey(0), 2, 24, 2, 16, fmt)
+        q = jnp.ones((2, 1, 4, 16), jnp.float32)
+        pos = jnp.zeros((2, 1), jnp.int32)
+        outer = jax.make_jaxpr(functools.partial(
+            daq.decode_attention_quant, bits=kv_bits(fmt), interpret=True))(
+            q, kc, ks, kc, ks, cb, pos)
+        body, = [e.params["jaxpr"] for e in _eqns(outer.jaxpr)
+                 if e.primitive.name == "pallas_call"]
+        selects = sum(e.primitive.name == "select_n" for e in _eqns(body))
+        parts = 2 if fmt == "q4" else 1
+        assert 0 < selects <= 8 * parts
 
 
 class TestDequantBitIdentity:
