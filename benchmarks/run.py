@@ -1,4 +1,4 @@
-"""Benchmark harness: one module per paper table/figure (+ roofline).
+"""Benchmark harness: one module per paper table/figure.
 Prints ``name,us_per_call,derived`` CSV; each module also self-checks its
 figure's paper claim and writes rows to results/bench/*.json.
 
@@ -15,7 +15,7 @@ from . import (fig1_llm_tradeoff, fig4_error_size, fig5_bits_histogram,
                fig18_formats, fig19_fp_formats, fig21_block_size,
                fig22_alpha_rule, fig23_search, fig24_huffman,
                fig28_compression_scaling, fig29_rotations, fig34_signmax,
-               roofline, serve_packed, table1_headline)
+               table1_headline)
 
 MODULES = {
     "fig4": fig4_error_size,
@@ -34,8 +34,6 @@ MODULES = {
     "fig11": fig11_fisher_kl,
     "fig12": fig12_fisher_structure,
     "table1": table1_headline,
-    "roofline": roofline,
-    "serve_packed": serve_packed,
 }
 
 

@@ -41,31 +41,15 @@
 #                                   (shared-prefix fork parity per family,
 #                                   pool eviction, priority/aging admission,
 #                                   submit/stream lifecycle, expiry
-#                                   accounting, deterministic traffic
-#                                   replay)
+#                                   accounting, bit-determinism of a fixed
+#                                   arrival list)
 #   scripts/run_tests.sh --kv       quantised KV cache tests only (fused
 #                                   flash-decode kernel parity vs oracle,
 #                                   write-path bit identity, format parsing
 #                                   + cache accounting, Fisher format
 #                                   allocation, per-family greedy drift,
 #                                   quantised prefix forks, slot-reset
-#                                   isolation, quantised_cache kill-switch)
-#   scripts/run_tests.sh --bench-smoke
-#                                   smallest decode batch sweep (full-size
-#                                   paper-100m, reduced batch points/reps)
-#                                   plus the fault drill, the seeded
-#                                   traffic replay and the KV-format sweep:
-#                                   enforces packed ≥ f32 tokens/s at every
-#                                   swept batch size with identical greedy
-#                                   tokens, every injected-fault recovery,
-#                                   goodput > 0 with no starvation,
-#                                   bit-deterministic replay across two
-#                                   runs, prefix reuse strictly cheaper
-#                                   than recompute, quantised KV ≤ 0.35×
-#                                   the f32 cache with bounded q8 drift,
-#                                   and a bit-identical quantised_cache=
-#                                   False kill-switch; exits non-zero on
-#                                   violation
+#                                   isolation)
 #   scripts/run_tests.sh [pytest args...]   any first argument that is not
 #                                   a target flag above (e.g. -k, -x, a
 #                                   test path) forwards untouched to the
@@ -105,11 +89,6 @@ fi
 if [ "${1:-}" = "--kv" ]; then
     shift
     exec python -m pytest -q tests/test_serve_kv_quant.py "$@"
-fi
-if [ "${1:-}" = "--bench-smoke" ]; then
-    shift
-    exec python -m benchmarks.serve_packed --sweep-only --fault-drill \
-        --traffic --kv-sweep "$@"
 fi
 if [ "${1:-}" = "--lint" ]; then
     shift

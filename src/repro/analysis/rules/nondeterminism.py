@@ -1,11 +1,11 @@
 """nondeterminism: hidden-global randomness / wall clock in step paths.
 
-Serving is drilled on bit-determinism — traffic replay signatures
-(``serve.traffic.deterministic_signature``), greedy-parity gates in the
-bench, per-(rid, token-index) sampling seeds. Unseeded global-state
-randomness (legacy ``np.random.*`` samplers, stdlib ``random``) or
-wall-clock reads (``time.time``) inside a step/serve path silently break
-replay without failing any test. Seed explicitly through
+Serving is drilled on bit-determinism — replays through the scheduler
+give identical tokens and step counts, packed serving matches the dense
+path token for token, sampling seeds per (rid, token-index). Unseeded
+global-state randomness (legacy ``np.random.*`` samplers, stdlib
+``random``) or wall-clock reads (``time.time``) inside a step/serve path
+silently break replay without failing any test. Seed explicitly through
 ``np.random.default_rng(seed)`` (or an ``np.random.Generator`` threaded
 from the caller); use ``time.monotonic()`` for latency metrics — it is
 allowed everywhere because it only feeds accounting, never compute.

@@ -10,6 +10,6 @@
 Each package: <name>.py (pl.pallas_call + BlockSpec), ops.py (jit'd wrapper
 with CPU fallback), ref.py (pure-jnp oracle). The kernel bodies are checked
 against the oracles in interpret mode on the CPU, compiled for a described
-TPU v5e by tests/test_tpu_compile.py, and run on the chip by chip_smoke.py.
+TPU v5e by tests/test_tpu_compile.py, and run on the chip by chipbench/.
 """
 from . import ops  # noqa: F401

@@ -34,9 +34,9 @@ picked per matmul geometry by the tuning table (``tune.choose_tiles``):
 
 Tile shapes ``(tm, tk, tn)`` come from ``tune.choose_tiles(M, K, N,
 bits)`` — an analytic roofline over the legal tile space, cached per
-geometry, pre-seedable from measured sweeps (see ``benchmarks/roofline.py``
-for the rendered terms). M is padded up to ``tm`` with zero rows (sliced off
-the output), so no divisibility constraint leaks to callers.
+geometry, pre-seedable from measured sweeps. M is padded up to ``tm``
+with zero rows (sliced off the output), so no divisibility constraint
+leaks to callers.
 
 Code layouts:
 

@@ -12,8 +12,7 @@ re-reads), the VPU work of the codebook select tree, and the MXU work of the
 scale expansion plus the main matmul (multi-pass for the f32 feed), and take
 the cheapest. The model is deliberately coarse — its job is to rank tile
 shapes, not predict microseconds, and none of its terms has been measured
-on the chip — and ``benchmarks/roofline.py`` renders the same terms next
-to measured serve shapes so the choices stay inspectable.
+on the chip.
 
 Resolved choices land in ``_TABLE``, an in-process tuning cache keyed by
 ``(M, K, N, bits, n_codes, block)``: each distinct matmul geometry pays the
@@ -80,9 +79,8 @@ def estimate(M: int, K: int, N: int, bits: int, tm: int, tk: int, tn: int,
              n_codes: int, decode: bool, block: int = BLOCK) -> dict:
     """Roofline terms for one (tiles, strategy) candidate.
 
-    Returns a dict of byte/flop terms plus ``time`` (seconds, coarse).
-    ``benchmarks/roofline.py`` renders these; :func:`choose_tiles` ranks
-    by ``time``."""
+    Returns a dict of byte/flop terms plus ``time`` (seconds, coarse);
+    :func:`choose_tiles` ranks by ``time``."""
     Mp = _pad_up(M, tm)
     m_sweeps = Mp // tm           # times the full weight stream is read
     n_sweeps = N // tn            # times x and the scale rows are re-read

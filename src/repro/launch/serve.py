@@ -3,16 +3,6 @@ batched requests with the continuous-batching engine.
 
     PYTHONPATH=src python -m repro.launch.serve --arch paper-100m \
         --variant small [--quantise babsmax128:int4] --requests 8
-
-``--traffic-replay <seed>`` switches to the scheduler front end
-(``serve.scheduler``) driven by a seeded replayable workload
-(``serve.traffic``): Poisson arrivals, a priority mix (``--priority``),
-and shared-prefix reuse (``--prefix``), with p50/p99 time-to-first-token
-and per-token latency plus goodput printed at exit:
-
-    PYTHONPATH=src python -m repro.launch.serve --arch paper-100m \
-        --variant smoke --traffic-replay 0 --requests 24 \
-        --priority 0:3,2:1 --prefix 8
 """
 from __future__ import annotations
 
@@ -91,26 +81,6 @@ def main(argv=None):
                     help="wall-clock watchdog for the whole run(): on "
                          "expiry, return resumable partial generations "
                          "instead of hanging on a stalled engine")
-    ap.add_argument("--traffic-replay", type=int, default=None,
-                    metavar="SEED",
-                    help="serve a seeded replayable workload through the "
-                         "scheduler front end (Poisson arrivals, priority/"
-                         "aging admission, shared-prefix KV reuse) and "
-                         "print p50/p99 TTFT + per-token latency and "
-                         "goodput at exit; --requests sets the workload "
-                         "size")
-    ap.add_argument("--rate", type=float, default=0.5,
-                    help="with --traffic-replay: mean arrivals per engine "
-                         "step of the Poisson process")
-    ap.add_argument("--priority", default="0:3,1:1", metavar="P:W,...",
-                    help="with --traffic-replay: priority mix as "
-                         "priority:weight pairs (higher priority admits "
-                         "sooner; an aging term prevents starvation)")
-    ap.add_argument("--prefix", type=int, default=8, metavar="LEN",
-                    help="with --traffic-replay: shared prompt-prefix "
-                         "length — requests declaring it fork pooled KV "
-                         "instead of re-prefilling; 0 disables prefix "
-                         "reuse")
     args = ap.parse_args(argv)
     use_compile_cache()
 
@@ -189,10 +159,6 @@ def main(argv=None):
             print(f"[serve]   group {i} [{g['format']}] "
                   f"{g['n_layers']} layer(s) x {g['length']} slots: "
                   f"{g['bytes']:,} bytes ({g['ratio_vs_dense']}x dense)")
-    if args.traffic_replay is not None:
-        report = _traffic_replay(eng, args)
-        _exit_if_degraded(eng)
-        return report
     rng = np.random.default_rng(0)
     for rid in range(args.requests):
         prompt = rng.integers(0, cfg.vocab, size=4).tolist()
@@ -255,50 +221,6 @@ def _auto_kv_format(cfg, fam, params, args) -> str:
     print(f"[serve] kv auto allocation under {args.kv_budget_bytes:,} B: "
           f"{','.join(fmts)} (~{total:,.0f} B resident KV)")
     return ",".join(fmts)
-
-
-def _traffic_replay(eng, args):
-    """--traffic-replay mode: seeded workload through the scheduler front
-    end, latency/goodput report at exit."""
-    from repro.serve import traffic
-
-    try:
-        priorities = tuple(
-            (float(p), float(w)) for p, w in
-            (pair.split(":") for pair in args.priority.split(",")))
-    except ValueError:
-        raise SystemExit(f"[serve] --priority {args.priority!r}: expected "
-                         "priority:weight pairs like 0:3,2:1")
-    use_prefix = args.prefix > 0
-    spec = traffic.TrafficSpec(
-        seed=args.traffic_replay, n_requests=args.requests, rate=args.rate,
-        vocab=eng.cfg.vocab, priorities=priorities,
-        prefixes=(("sys", args.prefix, 0.6),) if use_prefix else (),
-        no_prefix_weight=0.4 if use_prefix else 1.0)
-    wl = traffic.generate(spec)
-    print(f"[serve] traffic replay: seed={spec.seed} "
-          f"{spec.n_requests} requests, rate={spec.rate}/step, "
-          f"priorities={args.priority}"
-          + (f", shared prefix of {args.prefix} tokens" if use_prefix
-             else ", prefix reuse off"))
-    report = traffic.replay(eng, wl, use_prefix=use_prefix,
-                            deadline_s=args.deadline_s)
-    m = report.metrics
-    print(f"[serve] {m['completed']}/{m['n_requests']} completed "
-          f"({m['failed']} failed, {m['truncated']} truncated) in "
-          f"{m['wall_s']}s over {m['steps_total']} steps")
-    print(f"[serve] TTFT p50/p99 {m['ttft_p50_s']}/{m['ttft_p99_s']}s, "
-          f"per-token p50/p99 {m['per_token_p50_s']}/"
-          f"{m['per_token_p99_s']}s")
-    print(f"[serve] goodput {m['goodput_tok_s']} tok/s "
-          f"({m['good_tokens']} good tokens), queue depth "
-          f"mean/max {m['queue_depth_mean']}/{m['queue_depth_max']}")
-    if use_prefix:
-        print(f"[serve] prefix reuse: {m['forks']} forks reused "
-              f"{m['forked_tokens']} prefill tokens "
-              f"({m['prefill_slot_steps']} + {m['pool_prefill_steps']} "
-              "pool prefill slot-steps spent)")
-    return report
 
 
 if __name__ == "__main__":

@@ -218,9 +218,7 @@ def rope(x, positions, theta: float):
 # set to head_dim. `QuantisedKV` is a plain pytree, so the pair rides layer
 # scans, `lax.switch` branches and the engine's state dict exactly like a
 # dense cache array; the cache-side functions below dispatch on it, keeping
-# one code path per model family with the dense path untouched (the
-# `quantised_cache=False` kill-switch is bit-exact because it *is* the old
-# code).
+# one code path per model family with the dense path untouched.
 
 class QuantisedKV(NamedTuple):
     """One cache stack's quantised storage: codes (..., S, K, hdc) uint8 +

@@ -77,8 +77,7 @@ Components
     still budgets ``prompt + max_new_tokens`` against the global-layer
     length only (rings never overflow). On gemma3's 5:1 local:global
     pattern this cuts resident cache ~6× at serving lengths (asymptote
-    26/4 layers; measured 0.23× uniform at the smoke benchmark's
-    kv_len=256).
+    26/4 layers; 0.23× the uniform cache at smoke size, kv_len=256).
 
     The cache *contents* quantise too (``cfg.kv_format``): each cache
     group stores block-scaled codebook rows instead of dense activations
@@ -89,11 +88,12 @@ Components
     rows inside the jitted step (``layers.update_kv_cache`` on a
     ``QuantisedKV`` stack); reads stream codes straight through the fused
     ``kernels.decode_attention`` flash-decode kernel (dequantise in VMEM
-    after the HBM read, identical ring/window/causal mask semantics —
-    q8 cuts the decode HBM stream ~3.8× vs f32, q4 ~7×, at 0.27×/0.14×
-    resident bytes). Every row is self-contained, so ring wraps, ragged
-    chunk padding, slot resets (a zero scale dequantises to the dense
-    wipe) and PrefixPool forks (``CacheSpec.state_keys`` enumerates the
+    after the HBM read, identical ring/window/causal mask semantics). A
+    q8 row holds head_dim code bytes plus one 4-byte scale against
+    4 · head_dim bytes in f32, 0.27× at head_dim 64 (q4 0.14×): a byte
+    ratio, not a measured speed. Every row is self-contained, so ring
+    wraps, ragged chunk padding, slot resets (a zero scale dequantises to
+    the dense wipe) and PrefixPool forks (``CacheSpec.state_keys`` enumerates the
     scale entries) work unchanged. ``kv_format`` is per group
     (``"q8"``/``"q4"`` broadcast, or a comma list — whisper's
     cross-attention KV always stays dense), chosen by hand or by the
@@ -101,24 +101,15 @@ Components
     group's cache rows by the paper's Eq. 5 sensitivity and
     ``core.allocation.allocate_kv_formats`` demotes least-sensitive
     groups first under a resident-byte budget (``launch.serve
-    --kv-format auto --kv-budget-bytes N``). The kill-switch is
-    ``ServeEngine(quantised_cache=False)``: the engine drops
-    ``cfg.kv_format`` before any state is built and reproduces the dense
-    path bit-for-bit.
+    --kv-format auto --kv-budget-bytes N``). An empty ``kv_format`` is
+    the dense cache.
 
     ``ServeEngine.weight_bytes()`` reports resident bytes broken out as
     codes / scales / codebooks / dense (comparable across architectures);
     ``ServeEngine.cache_bytes()`` reports the decode-cache side — per
     cache group (windowed vs global, with the code/scale byte split and
-    per-group format) against the uniform full-length dense baseline. ``benchmarks/serve_packed.py`` measures tokens/s, weight
-    bytes and cache bytes per family (``--arch`` selects) and emits the
-    machine-readable ``BENCH_serve.json`` perf record with per-family
-    resident ratios. Measured (babsmax64:n4, packed vs the f32 master):
-    paper-100m-small 0.133×, tied paper-100m 0.133× (embed packed, no
-    dense unembed), rwkv6 smoke 0.140×, whisper smoke 0.138×, qwen2-moe
-    smoke 0.161× with expert stacks packed, gemma3 smoke 0.146× weights
-    and 0.23× cache — greedy tokens identical to the dense path in every
-    family.
+    per-group format) against the uniform full-length dense baseline.
+    ``chipbench/`` measures the serving path on the chip.
 
 The scheduling front end
 ------------------------
@@ -167,17 +158,6 @@ traffic can hit:
   the degraded-mode fallback and watchdog behave exactly as under direct
   ``engine.run``.
 
-``traffic`` generates deterministic replayable workloads (seeded Poisson
-arrivals on the virtual clock, mixed prompt/output lengths, prefix-group
-and priority mixes, optional ``serve.faults`` NaN windows) and
-``traffic.replay`` measures p50/p99 TTFT and per-token latency, goodput
-(completed tokens/s excluding failed/truncated) and queue depth — two
-replays of one spec are bit-deterministic (token streams + step-clock
-accounting), which ``benchmarks/serve_packed.py --traffic`` records in
-``BENCH_serve.json`` (``traffic`` section) and gates, together with
-prefix reuse being strictly cheaper than recompute on identical greedy
-tokens.
-
 The robustness layer
 --------------------
 Serving on aggressively quantised weights concentrates failure into two
@@ -185,8 +165,7 @@ sharp modes — a corrupted packed stream decodes to unbounded garbage
 (absmax block scales amplify a single flipped word), and a poisoned slot
 NaNs its logits — so fault tolerance is part of the serving path, not an
 afterthought. Every recovery path below has a deterministic injector in
-``serve.faults`` and is drilled by ``tests/test_serve_faults.py`` and
-``benchmarks/serve_packed.py --fault-drill``:
+``serve.faults`` and is drilled by ``tests/test_serve_faults.py``:
 
 * **Load-time integrity** — ``ServeEngine.from_quantised(validate=True)``
   runs ``QuantisationPlan.verify_packed`` over the packed checkpoint:
@@ -227,10 +206,9 @@ afterthought. Every recovery path below has a deterministic injector in
     drop/duplicate faults — each returning counter state so tests assert
     the fault actually fired.
 
-``scheduler`` / ``traffic``
+``scheduler``
     The front end described above: ``Scheduler``/``StreamHandle``/
-    ``PrefixPool``, and the seeded workload generator + replay driver
-    behind the traffic benchmarks.
+    ``PrefixPool``.
 
 ``context_parallel``
     Flash-decode attention over a sequence-sharded KV cache (exact
@@ -260,14 +238,12 @@ scaling, tensors whose output dim does not tile by the block — e.g.
 zamba2's 548-wide in_proj in smoke) are dequantised at load.
 """
 from . import (cache, context_parallel, engine, faults,  # noqa: F401
-               scheduler, traffic)
+               scheduler)
 from .cache import CacheGroup, CacheSpec, build_cache_spec
 from .engine import Request, ServeEngine, greedy_generate, host_to_device
 from .scheduler import PrefixPool, Scheduler, StreamHandle
-from .traffic import TrafficSpec, Workload
 
 __all__ = ["cache", "context_parallel", "engine", "faults", "scheduler",
-           "traffic", "CacheGroup", "CacheSpec", "build_cache_spec",
+           "CacheGroup", "CacheSpec", "build_cache_spec",
            "Request", "ServeEngine", "greedy_generate", "host_to_device",
-           "PrefixPool", "Scheduler", "StreamHandle", "TrafficSpec",
-           "Workload"]
+           "PrefixPool", "Scheduler", "StreamHandle"]

@@ -209,15 +209,7 @@ class ServeEngine:
     def __init__(self, cfg: ModelConfig, params, batch_slots: int = 4,
                  kv_len: int = 256, prefill_chunk: int = 8,
                  strict_admission: bool = True, windowed_cache: bool = True,
-                 step_retries: int = 1, dense_fallback: bool = True,
-                 quantised_cache: bool = True):
-        # quantised_cache=False is the KV-format kill-switch: the engine
-        # drops cfg.kv_format before any state or step is built, so decode
-        # runs the dense bit-exact pre-quantisation path regardless of what
-        # the config asks for (the cache analogue of windowed_cache=False).
-        if not quantised_cache and cfg.kv_format:
-            cfg = cfg.replace(kv_format="")
-        self.quantised_cache = quantised_cache
+                 step_retries: int = 1, dense_fallback: bool = True):
         self.cfg = cfg
         self.fam = get_family(cfg.family)
         if not getattr(self.fam, "supports_ragged", False):

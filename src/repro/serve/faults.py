@@ -25,9 +25,8 @@ Injectors that wrap engine internals (``_step`` / ``_fill_slots``)
 monkeypatch the *instance*, never the class, and return their counter
 state so tests can assert the fault actually fired. Step indices count
 ``run()`` device steps (prefill chunks included) from the moment of
-injection. Used by ``tests/test_serve_faults.py`` and the
-``benchmarks/serve_packed.py --fault-drill`` mode (which records drill
-outcomes in ``BENCH_serve.json``).
+injection. Used by ``tests/test_serve_faults.py`` and
+``tests/test_serve_traffic.py``.
 """
 from __future__ import annotations
 

@@ -42,8 +42,8 @@ deployment needs and a drain-the-queue loop cannot provide:
 
 **Virtual clock**: scheduling decisions are driven by the engine step
 counter (``vt = steps_total * step_dt`` plus idle fast-forward), never the
-wall clock — a replayed workload (``serve.traffic``) admits in exactly the
-same order every run, so traffic benchmarks are bit-deterministic.
+wall clock — a fixed list of arrivals admits in exactly the same order
+every run, so a replay is bit-deterministic.
 Wall-clock latency stamps (``Generation.t_submit``/``t_admit``/
 ``t_first_token``/``t_done``) ride on the result objects for reporting.
 """
@@ -321,7 +321,7 @@ class Scheduler:
     aging * steps_waited`` — 0 is strict priority (may starve), the
     default guarantees a bounded wait for every request. ``step_dt`` maps
     engine steps to the virtual-clock units of ``submit(at=...)`` arrival
-    times (``serve.traffic`` workloads).
+    times.
     """
 
     def __init__(self, engine: ServeEngine, *, aging: float = 0.05,

@@ -1,11 +1,15 @@
-"""Config-exactness guards (the assigned hyperparameters, verbatim) and the
-entropy-coded checkpoint round trip."""
+"""Config-exactness guards (the assigned hyperparameters, verbatim), the
+entropy-coded checkpoint round trip, and where the compile cache goes."""
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import configs
+
+ROOT = Path(__file__).resolve().parents[1]
 
 # the assigned table, verbatim — guards against config drift
 ASSIGNED = {
@@ -105,3 +109,18 @@ class TestCompressedCheckpoint:
         with open(os.path.join(path, "manifest.json")) as f:
             man = json.load(f)
         assert 2.5 < man["achieved_bits_per_param"] < 3.6
+
+
+def test_compile_cache_placement(monkeypatch):
+    from repro.launch import compile_cache
+    seen = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: seen.append((k, v)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    assert compile_cache.use_compile_cache() == "/elsewhere"
+    assert seen == []              # JAX reads the variable itself
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = compile_cache.use_compile_cache()
+    assert path == str(ROOT / ".jax_cache")
+    assert seen == [("jax_compilation_cache_dir", path)]
+    assert ".jax_cache/" in (ROOT / ".gitignore").read_text().split()

@@ -2,7 +2,10 @@
 layer exercised against real injected faults (``serve.faults``) —
 checkpoint integrity rejection by tensor name, slot quarantine with
 bit-identical survivors, deadlines, the run() watchdog, step retry, the
-dense degraded-mode fallback, and admission faults."""
+dense degraded-mode fallback, and admission faults — and the
+no-hidden-fallback contract: a kernel failure in a packed engine built
+with ``dense_fallback=False`` propagates, and the serve launcher exits
+non-zero naming the error when an engine degrades."""
 import warnings
 
 import jax
@@ -11,6 +14,7 @@ import pytest
 
 from repro import configs
 from repro.core import IntegrityError, build_plan, verify_packed_tree
+from repro.kernels import ops
 from repro.models import api as mapi
 from repro.serve import faults
 from repro.serve.engine import Request, ServeEngine
@@ -316,3 +320,38 @@ class TestAdmissionFaults:
         assert state["duplicated"] == 1
         assert len(gens) == 2
         assert gens[0].tokens == gens[1].tokens  # greedy → same stream
+
+
+def _packed_engine(dense_fallback):
+    cfg = configs.get_config("paper-100m", "smoke")
+    params = mapi.get_family(cfg.family).init(jax.random.PRNGKey(0), cfg)
+    plan = build_plan(params, "babsmax64:n4")
+    return ServeEngine.from_quantised(
+        cfg, plan.quantise(params), plan, batch_slots=2, kv_len=32,
+        prefill_chunk=4, dense_fallback=dense_fallback)
+
+
+def _kernel_refused(*a, **k):
+    # what Mosaic raises for a lowering it does not support
+    raise NotImplementedError("Only 2D gather is supported")
+
+
+def test_kernel_failure_propagates_without_dense_fallback(monkeypatch):
+    eng = _packed_engine(dense_fallback=False)
+    monkeypatch.setattr(ops, "dequant_matmul", _kernel_refused)
+    eng.submit(Request(prompt=[1, 2, 3], max_new_tokens=2))
+    with pytest.raises(NotImplementedError, match="2D gather"):
+        eng.run()
+    assert not eng.degraded
+
+
+def test_serve_launcher_exits_when_degraded(monkeypatch, capsys):
+    from repro.launch import serve
+    monkeypatch.setattr(serve, "use_compile_cache", lambda: "")
+    monkeypatch.setattr(ops, "dequant_matmul", _kernel_refused)
+    with pytest.warns(RuntimeWarning, match="degraded mode"):
+        with pytest.raises(SystemExit) as e:
+            serve.main(["--variant", "smoke", "--quantise", "babsmax64:n4",
+                        "--packed", "--requests", "1", "--max-new", "2"])
+    msg = str(e.value.code)
+    assert "degraded to dense" in msg and "2D gather" in msg

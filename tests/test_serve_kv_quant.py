@@ -3,9 +3,9 @@
 ring-wrapped caches and ragged multi-token chunks; write-path bit identity
 (``quantise_kv`` → kernel dequant == ``block_quant`` → ``block_dequant``);
 format parsing + cache-byte accounting; Fisher format allocation; and the
-serving stack end to end — per-family greedy drift under q8, prefix forks
-copying quantised rows, slot-reset isolation, and the ``quantised_cache``
-kill-switch reproducing the dense engine bit-exactly."""
+serving stack end to end — per-family greedy drift under q8, the engine
+against the single-sequence greedy path with and without a quantised
+cache, prefix forks copying quantised rows, and slot-reset isolation."""
 import functools
 import warnings
 
@@ -376,26 +376,23 @@ class TestGreedyDrift:
 
 
 class TestEngineQuantised:
-    def test_killswitch_bit_exact(self, params):
-        """quantised_cache=False on a q8 config reproduces the dense
-        engine bit-for-bit — tokens and cache allocation."""
-        cfg_q = CFG.replace(kv_format="q8")
-        ref = _run_tokens(ServeEngine(CFG, params, **ENG_KW), PROMPTS)
-        eng = ServeEngine(cfg_q, params, quantised_cache=False, **ENG_KW)
-        assert not eng.cfg.kv_format
-        assert _run_tokens(eng, PROMPTS) == ref
-        dense_cb = ServeEngine(CFG, params, **ENG_KW).cache_bytes()
-        assert eng.cache_bytes() == dense_cb
-
-    def test_q8_engine_matches_greedy(self, params):
-        """The batched engine with a quantised cache agrees with the
-        single-sequence greedy path under the same format."""
-        cfg_q = CFG.replace(kv_format="q8")
-        done = _run_tokens(ServeEngine(cfg_q, params, **ENG_KW), PROMPTS)
+    @pytest.mark.parametrize("kv_format", ["", "q8"])
+    def test_q8_engine_matches_greedy(self, params, kv_format):
+        """The batched engine agrees with the single-sequence greedy path
+        under the same cache format; the dense cache ("") allocates what
+        an engine on the dense config does, q8 less."""
+        cfg = CFG.replace(kv_format=kv_format)
+        eng = ServeEngine(cfg, params, **ENG_KW)
+        done = _run_tokens(eng, PROMPTS)
         for i, p in enumerate(PROMPTS):
-            ref = greedy_generate(cfg_q, params,
+            ref = greedy_generate(cfg, params,
                                   np.asarray([p], np.int32), 6, kv_len=64)
             assert done[i] == list(ref[0]), f"prompt {i} diverged"
+        dense_cb = ServeEngine(CFG, params, **ENG_KW).cache_bytes()
+        if kv_format:
+            assert eng.cache_bytes()["kv"] < dense_cb["kv"]
+        else:
+            assert eng.cache_bytes() == dense_cb
 
     def test_prefix_fork_quantised(self, params):
         """PrefixPool forks copy quantised code + scale rows verbatim:

@@ -1,14 +1,14 @@
 """Compile rehearsal for the TPU v5e: the serving path's Pallas kernels at
-paper-100m's and Zamba2-7B's widths, and one whole decode step of the chip
-smoke's engine and of the ``zamba2-7b`` benchmark configuration, compiled
-for a described (not attached) ``v5e:2x2`` chip. Nothing runs;
-the TPU compiler refuses here what it would refuse on the chip (illegal
-block shapes, unsupported lowerings, VMEM or HBM overflows).
+paper-100m's and Zamba2-7B's widths, and one whole decode step of each
+workload of ``BENCHMARK.json``, compiled for a described (not attached)
+``v5e:2x2`` chip. Nothing runs; the TPU compiler refuses here what it
+would refuse on the chip (illegal block shapes, unsupported lowerings,
+VMEM or HBM overflows).
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library at a time, so collection must
 not touch it. Every test of this kind lives in this one file."""
-import importlib.util
+import json
 from pathlib import Path
 
 import jax
@@ -31,6 +31,8 @@ ZAMBA2_PROJECTIONS = [(3584, 14592), (7168, 3584), (7168, 7168),
                       (3584, 28672), (3584, 128), (128, 28672),
                       (14336, 3584), (3584, 3584)]
 HBM_BYTES = 16 * 2 ** 30
+BENCH = json.loads(
+    (Path(__file__).resolve().parents[1] / "BENCHMARK.json").read_text())
 
 
 @pytest.fixture(scope="module")
@@ -172,63 +174,25 @@ def test_block_quant_kv_write(one_chip):
     assert _calls(c) == {"block_quant": 1}
 
 
-def _chip_smoke():
-    path = Path(__file__).resolve().parents[1] / "chip_smoke.py"
-    spec = importlib.util.spec_from_file_location("chip_smoke", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_smoke_engine_decode_step(one_chip, monkeypatch):
-    """The chip smoke's whole prefill step (packed weights, q8 cache, tied
-    unembed) at full width: every kernel is a custom call, and the program
-    fits one chip's HBM."""
-    from repro.core import build_plan
-    from repro.models.api import get_family
-    from repro.serve.engine import alloc_decode_state
-
-    cs = _chip_smoke()
-    # the program's kernel choice asks jax.default_backend(), which is the
-    # CPU here: steer it to the TPU path this compile is for
-    monkeypatch.setattr(ops, "on_tpu", lambda: True)
-    cfg = cs.model_config()
-    fam = get_family(cfg.family)
-    shapes = jax.eval_shape(lambda: fam.init(jax.random.PRNGKey(0), cfg))
-    plan = build_plan(shapes, cs.FORMAT)
-    packed = jax.eval_shape(lambda p: plan.pack(p, fam.pack_layouts(cfg)),
-                            shapes)
-    state = jax.eval_shape(lambda: alloc_decode_state(
-        fam, cfg, cs.SLOTS, cs.KV_LEN, slack=cs.CHUNK))
-    batch = cs._step_batches(cs.SLOTS, cs.CHUNK)["prefill+reset"]
-    place = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                           sharding=one_chip)
-    step = jax.jit(lambda p, s, b: fam.decode_step(p, s, b, cfg))
-    compiled = step.lower(jax.tree.map(place, packed),
-                          jax.tree.map(place, state),
-                          jax.tree.map(place, batch)).compile()
-    calls = _calls(compiled)
-    assert all(calls.get(k) for k in ops.KERNEL_NAMES), calls
-    mem = compiled.memory_analysis()
-    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    assert used < HBM_BYTES, used
-
-
-def test_zamba2_7b_decode_step(one_chip, monkeypatch):
-    """The ``zamba2-7b`` cell's prefill step with the admission reset (its
-    largest), packed 4-bit with q8 caches over 32 slots: every kernel is a
-    custom call, and the program fits one chip's HBM."""
-    import json
+@pytest.mark.parametrize("workload", [w["name"] for w in BENCH["workloads"]])
+def test_cell_decode_step(one_chip, monkeypatch, workload):
+    """The cell's prefill step with the admission reset (its largest),
+    packed as the cell serves it: every kernel the cell's configuration
+    names is a custom call, and the program fits one chip's HBM. The
+    cells together name every kernel of ``ops.KERNEL_NAMES``
+    (``dequant_matmul_t`` through zamba2's tied head)."""
     from chipbench import harness
     from repro.core import build_plan
     from repro.models.api import get_family
     from repro.serve.engine import alloc_decode_state
 
+    # the program's kernel choice asks jax.default_backend(), which is the
+    # CPU here: steer it to the TPU path this compile is for
     monkeypatch.setattr(ops, "on_tpu", lambda: True)
-    root = Path(__file__).resolve().parents[1]
-    cell = harness.load_cell(
-        json.loads((root / "BENCHMARK.json").read_text()), "zamba2-7b.steady")
+    cells = [harness.load_cell(BENCH, w["name"]) for w in BENCH["workloads"]]
+    assert (set().union(*(c.config["kernels"] for c in cells))
+            == set(ops.KERNEL_NAMES))
+    cell = harness.load_cell(BENCH, workload)
     cfg = harness.program_config(cell.config)
     serving = cell.config["serving"]
     fam = get_family(cfg.family)
@@ -248,7 +212,8 @@ def test_zamba2_7b_decode_step(one_chip, monkeypatch):
                           jax.tree.map(place, state),
                           jax.tree.map(place, batch)).compile()
     calls = _calls(compiled)
-    assert all(calls.get(k) for k in ops.KERNEL_NAMES), calls
+    assert set(calls) <= set(ops.KERNEL_NAMES), calls
+    assert all(calls.get(k) for k in cell.config["kernels"]), calls
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
